@@ -72,15 +72,21 @@ GPUS_PER_POD = 8
 LEGACY = dict(subset_scoring=False, slot_engine="heap")
 
 
+def make_topology(n_nodes: int) -> ClusterTopology:
+    """The bench's cluster shape: 8-GPU nodes, 32 per leaf, 4 leaves per
+    spine, 4 spines per superspine, 32-node HBDs."""
+    return ClusterTopology(
+        n_nodes=n_nodes, gpus_per_node=8, nodes_per_leaf=32,
+        leaves_per_spine=4, spines_per_superspine=4, nodes_per_hbd=32)
+
+
 def make_state(n_nodes: int, seed: int = 0) -> ClusterState:
     """A fragmented cluster: ~60% of nodes partially or fully busy.
 
     Vectorized setup — the old per-node loop took minutes at 1M nodes;
     one broadcast writes the same busy pattern in O(n) numpy.
     """
-    topo = ClusterTopology(
-        n_nodes=n_nodes, gpus_per_node=8, nodes_per_leaf=32,
-        leaves_per_spine=4, spines_per_superspine=4, nodes_per_hbd=32)
+    topo = make_topology(n_nodes)
     state = ClusterState.create(topo)
     rng = np.random.default_rng(seed)
     busy_nodes = rng.random(n_nodes) < 0.6
@@ -169,9 +175,7 @@ def _placement_key(jobs):
 
 def _run_sim(n_nodes, policy, strategy, *, rsch_kw=None, n_jobs=48,
              seed=0, pipelined=False):
-    topo = ClusterTopology(
-        n_nodes=n_nodes, gpus_per_node=8, nodes_per_leaf=32,
-        leaves_per_spine=4, spines_per_superspine=4, nodes_per_hbd=32)
+    topo = make_topology(n_nodes)
     state = ClusterState.create(topo)
     quota = QuotaManager({f"t{i}": {0: 10 ** 9} for i in range(3)})
     rsch = RSCH(topo, RSCHConfig(train_strategy=strategy,
@@ -222,10 +226,7 @@ def trace_replay(n_nodes: int, n_jobs: int, seed: int) -> dict:
                 kind=JobKind.TRAIN) for i in range(n_jobs)]
 
     def replay(pipelined):
-        topo = ClusterTopology(
-            n_nodes=n_nodes, gpus_per_node=8, nodes_per_leaf=32,
-            leaves_per_spine=4, spines_per_superspine=4,
-            nodes_per_hbd=32)
+        topo = make_topology(n_nodes)
         state = ClusterState.create(topo)
         quota = QuotaManager({f"t{i}": {0: 10 ** 9} for i in range(4)})
         rsch = RSCH(topo,

@@ -97,8 +97,7 @@ def place_and_price(bg_name: str, bg_profiles: ProfileSet, topo, terms,
         return None
     q = placement_quality(res.placement, topo, job.n_gpus)
     t = estimated_step_time(terms, q)
-    from repro.launch.cosched import effective_collective_bw
-    from repro.launch.mesh import ICI_BW
+    from repro.launch.cosched import ICI_BW, effective_collective_bw
     coll = terms["collective"] * ICI_BW / effective_collective_bw(q)
     print(f"  bg={bg_name:10s}: nodes={q.n_nodes} "
           f"groups={q.n_groups} node_dev={q.node_dev:.2f} "

@@ -105,8 +105,8 @@ def main():
     print("\n== Part 2: serve a placed model (continuous batching) ==")
     from repro.launch.serve import serve_demo
     # Legacy shim on purpose — see the module docstring for why.
-    finished = serve_demo("glm4-9b", requests=10, batch_size=4, max_new=6,
-                          per_slot=False)
+    finished, _ = serve_demo("glm4-9b", requests=10, batch_size=4,
+                             max_new=6, per_slot=False)
     assert len(finished) == 10
     print("inference_cluster complete")
 

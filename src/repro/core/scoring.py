@@ -88,13 +88,18 @@ def node_scores_np(free: np.ndarray, used: np.ndarray, mask: np.ndarray,
                    group_load: np.ndarray, topo_pref: np.ndarray,
                    request: int, gpus_per_node: int,
                    weights: ScoreWeights) -> np.ndarray:
-    """Reference numpy implementation (semantics match the Pallas kernel)."""
+    """Reference numpy implementation.
+
+    The score is the Pallas kernel's f32 expression, term for term and in
+    the same order (``w_used * used * inv_g`` first), so that the two
+    agree bit for bit: the gang slot walk breaks ties exactly, and a
+    score one ulp apart can move a placement."""
     free = free.astype(np.float32)
     used = used.astype(np.float32)
     valid = mask & (free >= float(request))
-    used_norm = used / float(gpus_per_node)
+    inv_g = 1.0 / float(gpus_per_node)
     exact_fit = (free == float(request)).astype(np.float32)
-    score = (weights.used * used_norm
+    score = (weights.used * used * inv_g
              + weights.fit * exact_fit
              + weights.group * group_load.astype(np.float32)
              + weights.topo * topo_pref.astype(np.float32))

@@ -31,7 +31,7 @@ def node_scores_ref(free: jnp.ndarray, used: jnp.ndarray,
     free_f = free.astype(jnp.float32)
     used_f = used.astype(jnp.float32)
     valid = (mask != 0) & (free_f >= float(request))
-    score = (w_used * used_f / float(gpus_per_node)
+    score = (w_used * used_f * (1.0 / float(gpus_per_node))
              + w_fit * (free_f == float(request)).astype(jnp.float32)
              + w_group * group_load.astype(jnp.float32)
              + w_topo * topo_pref.astype(jnp.float32))

@@ -11,7 +11,9 @@ output through HBM.
 
 Layout / grid:
 
-* inputs r, k, v, w: ``(B, T, H, n)`` — the natural stream layout;
+* inputs r, k, v, w: ``(B, T, H, n)`` — the natural stream layout,
+  which the wrapper transposes to ``(B, H, T, n)`` so that each stream
+  block ``(1, 1, TB, n)`` is tiled as the TPU requires;
 * grid ``(B, H, T // TB)`` with ``dimension_semantics``
   ``("parallel", "parallel", "arbitrary")`` — time is the sequential
   grid axis, so the ``(n, n)`` state lives in a VMEM scratch buffer that
@@ -48,7 +50,7 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
                 o_ref, sT_ref, state, *, tb: int, n_tblocks: int) -> None:
     """One (b, h, time-block) grid step.
 
-    r/k/v/w_ref, o_ref: (1, TB, 1, n) VMEM blocks; u_ref: (1, n);
+    r/k/v/w_ref, o_ref: (1, 1, TB, n) VMEM blocks; u_ref: (1, 1, n);
     s0_ref, sT_ref: (1, 1, n, n); state: (n, n) f32 VMEM scratch.
     """
     tc = pl.program_id(2)
@@ -57,18 +59,21 @@ def _wkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
     def _init():
         state[...] = s0_ref[0, 0].astype(jnp.float32)
 
-    u = u_ref[0].astype(jnp.float32)                     # (n,)
+    u = u_ref[0].astype(jnp.float32)                     # (1, n)
 
     def step(t, carry):
-        r_t = r_ref[0, t, 0, :].astype(jnp.float32)      # (n,)
-        k_t = k_ref[0, t, 0, :].astype(jnp.float32)
-        v_t = v_ref[0, t, 0, :].astype(jnp.float32)
-        w_t = w_ref[0, t, 0, :].astype(jnp.float32)
+        # One time step as (1, n) rows: the TPU matmul takes 2-D operands.
+        row = pl.ds(t, 1)
+        r_t = r_ref[0, 0, row, :].astype(jnp.float32)    # (1, n)
+        k_t = k_ref[0, 0, row, :].astype(jnp.float32)
+        v_t = v_ref[0, 0, row, :].astype(jnp.float32)
+        w_t = w_ref[0, 0, row, :].astype(jnp.float32)
         S = state[...]                                   # (n, n)
         # o_t[m] = sum_n r[n] (S[n,m] + u[n] k[n] v[m])
-        o_t = r_t @ S + jnp.sum(r_t * u * k_t) * v_t
-        o_ref[0, t, 0, :] = o_t.astype(o_ref.dtype)
-        state[...] = w_t[:, None] * S + k_t[:, None] * v_t[None, :]
+        o_t = (jnp.dot(r_t, S, preferred_element_type=jnp.float32)
+               + jnp.sum(r_t * u * k_t) * v_t)
+        o_ref[0, 0, row, :] = o_t.astype(o_ref.dtype)
+        state[...] = w_t.T * S + k_t.T * v_t
         return carry
 
     jax.lax.fori_loop(0, tb, step, 0)
@@ -93,13 +98,17 @@ def wkv6_pallas(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         raise ValueError(f"T={T} not divisible by time block {tb}")
     n_tblocks = T // tb
 
-    stream = pl.BlockSpec((1, tb, 1, n), lambda b, h, t: (b, t, h, 0))
+    # The kernel streams (B, H, T, n): a (1, 1, tb, n) block's last two
+    # dims are a multiple of 8 and the full head dim, which the TPU
+    # lowering requires of a block (a (1, tb, 1, n) block of the natural
+    # (B, T, H, n) layout is refused wherever H > 1).
+    stream = pl.BlockSpec((1, 1, tb, n), lambda b, h, t: (b, h, t, 0))
     state_spec = pl.BlockSpec((1, 1, n, n), lambda b, h, t: (b, h, 0, 0))
-    u_spec = pl.BlockSpec((1, n), lambda b, h, t: (h, 0))
+    u_spec = pl.BlockSpec((1, 1, n), lambda b, h, t: (h, 0, 0))
     kernel = functools.partial(_wkv_kernel, tb=tb, n_tblocks=n_tblocks)
 
     out_shapes = (
-        jax.ShapeDtypeStruct((B, T, H, n), jnp.float32),
+        jax.ShapeDtypeStruct((B, H, T, n), jnp.float32),
         jax.ShapeDtypeStruct((B, H, n, n), jnp.float32),
     )
     kwargs = {}
@@ -115,5 +124,6 @@ def wkv6_pallas(r: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         scratch_shapes=[pltpu.VMEM((n, n), jnp.float32)],
         interpret=interpret,
         **kwargs,
-    )(r, k, v, w, u, s0)
-    return o, sT
+    )(*(x.transpose(0, 2, 1, 3) for x in (r, k, v, w)),
+      u.reshape(H, 1, n), s0)
+    return o.transpose(0, 2, 1, 3), sT

@@ -26,7 +26,10 @@ import numpy as np
 
 from ..core.job import Placement
 from ..core.topology import ClusterTopology
-from .mesh import ICI_BW
+from .mesh import V5E, chip_peaks
+
+# The placed jobs run on v5e chips; collectives ride their ICI links.
+ICI_BW = chip_peaks(V5E).ici_bw
 
 # Inter-group (leaf-crossing) links run at a fraction of intra-group ICI;
 # 4x oversubscription at the leaf->spine uplink is typical for AI fabrics.

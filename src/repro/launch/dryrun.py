@@ -52,7 +52,7 @@ from ..sharding.auto import (ShardingRules, batch_specs,
 from ..train.optim import opt_specs
 from ..train.step import make_train_step
 from .combo_cache import ComboCache, mesh_key
-from .mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16, make_production_mesh
+from .mesh import V5E, chip_peaks, make_production_mesh
 
 # Memoization for sweeps that revisit (arch × shape × mesh) combos —
 # e.g. elastic-plan estimation probing one architecture at several chip
@@ -258,6 +258,7 @@ def analyse(lowered, cfg: ArchConfig, shape: InputShape, n_chips: int
     coll_total = float(hlo["collective_bytes_per_device"])
 
     mf = model_flops(cfg, shape)
+    peaks = chip_peaks(V5E)
     flops_global = flops_dev * n_chips
     result = {
         "arch": cfg.name, "shape": shape.name, "chips": n_chips,
@@ -270,9 +271,9 @@ def analyse(lowered, cfg: ArchConfig, shape: InputShape, n_chips: int
         "memory_analysis": mem_info,
         "model_flops_global": mf,
         "useful_flops_ratio": (mf / flops_global) if flops_global else 0.0,
-        "compute_term_s": flops_dev / PEAK_FLOPS_BF16,
-        "memory_term_s": bytes_dev / HBM_BW,
-        "collective_term_s": coll_total / ICI_BW,
+        "compute_term_s": flops_dev / peaks.flops_bf16,
+        "memory_term_s": bytes_dev / peaks.hbm_bw,
+        "collective_term_s": coll_total / peaks.ici_bw,
     }
     terms = {"compute": result["compute_term_s"],
              "memory": result["memory_term_s"],

@@ -18,6 +18,7 @@ from ..ckpt import save_checkpoint
 from ..configs import get_arch
 from ..data import DataConfig, synthetic_batches
 from ..train import AdamWConfig, TrainState
+from .compile_cache import enable_compile_cache
 
 
 def train_loop(arch: str, *, smoke: bool = True, steps: int = 20,
@@ -53,6 +54,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt", default="")
     args = ap.parse_args()
+    enable_compile_cache()
     train_loop(args.arch, smoke=args.smoke, steps=args.steps,
                batch=args.batch, seq=args.seq, lr=args.lr,
                ckpt_dir=args.ckpt)

@@ -8,6 +8,7 @@ import pytest
 
 from repro.launch.hlo_analysis import (HloModule, analyse_hlo_text,
                                        top_contributors)
+from repro.launch.mesh import make_mesh
 
 
 def _compile_text(fn, *specs):
@@ -102,7 +103,7 @@ def test_top_contributors_orders_by_weight():
 
 
 def test_collective_parse_on_sharded_program():
-    mesh = jax.make_mesh((1,), ("x",))
+    mesh = make_mesh((1,), ("x",))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def f(a):

@@ -2,6 +2,7 @@
 
 import jax
 import numpy as np
+import pytest
 
 from repro.core import (ClusterState, Job, JobKind, QSCH, QSCHConfig,
                         QueuePolicy, QuotaManager, RSCH, RSCHConfig,
@@ -10,7 +11,8 @@ from repro.core.topology import small_topology
 from repro.launch.cosched import (effective_collective_bw,
                                   estimated_step_time, job_mesh_shape,
                                   placement_quality)
-from repro.launch.mesh import ICI_BW
+from repro.launch.cosched import ICI_BW
+from repro.launch.mesh import make_cpu_mesh
 
 
 def _run_sim(strategy, jobs, n_nodes=16):
@@ -99,7 +101,7 @@ def test_scheduled_job_trains_on_cpu_mesh():
     assert res.placement is not None
     data, model_par = job_mesh_shape(res.placement.n_gpus)
     # 1 GPU -> (1,1) mesh over the single real CPU device
-    mesh = jax.make_mesh((data, model_par), ("data", "model"))
+    mesh = make_cpu_mesh(data, model_par)
     cfg = get_arch("glm4-9b", smoke=True)
     m = Model(cfg)
     params = m.init(jax.random.PRNGKey(0))
@@ -109,3 +111,30 @@ def test_scheduled_job_trains_on_cpu_mesh():
     batch = make_inputs(cfg, batch=2, seq=16, kind="train")
     _, _, metrics = step(params, adamw_init(params), batch)
     assert np.isfinite(float(metrics["loss"]))
+
+
+def test_chip_peaks_table_is_keyed_by_device_kind():
+    from repro.launch.mesh import V5E, chip_peaks
+    assert chip_peaks(V5E).flops_bf16 == 197e12
+    assert chip_peaks(V5E).ici_bw == ICI_BW
+    with pytest.raises(KeyError):
+        chip_peaks("TPU v1 unknown")
+
+
+def test_compile_cache_dir_env_wins_else_repo_path(monkeypatch, tmp_path):
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from repro.launch import compile_cache
+
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv(compile_cache.ENV_VAR, str(tmp_path))
+    assert compile_cache.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv(compile_cache.ENV_VAR)
+    try:
+        path = compile_cache.enable_compile_cache()
+        assert path == str(compile_cache.REPO_CACHE_DIR)
+        assert path.endswith(".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+        cc.reset_cache()

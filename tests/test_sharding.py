@@ -7,6 +7,7 @@ import pytest
 from jax.sharding import PartitionSpec as P
 
 from repro.configs import ARCH_IDS, get_arch
+from repro.launch.mesh import make_cpu_mesh
 from repro.models import Model
 from repro.sharding.auto import (ShardingRules, batch_specs,
                                  cache_specs_sharding, param_shardings,
@@ -17,15 +18,14 @@ from repro.sharding.auto import (ShardingRules, batch_specs,
 def rules():
     # A (4, 2) CPU mesh stands in for (data, model); the rule table only
     # reads axis sizes, so divisibility semantics are identical.
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_cpu_mesh(1, 1)
     return ShardingRules(mesh)
 
 
 @pytest.fixture(scope="module")
 def rules_16x16():
     from jax.sharding import AbstractMesh
-    # jax 0.4.x takes one shape tuple of (name, size) pairs.
-    return ShardingRules(AbstractMesh((("data", 16), ("model", 16))))
+    return ShardingRules(AbstractMesh((16, 16), ("data", "model")))
 
 
 def test_mlp_rules(rules_16x16):

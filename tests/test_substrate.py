@@ -162,9 +162,10 @@ def test_seq_shard_context_resolves_only_when_enabled():
     import jax
     from jax.sharding import Mesh
     import numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.sharding.context import ActivationSharding
 
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     off = ActivationSharding(mesh, seq_shard=False)
     on = ActivationSharding(mesh, seq_shard=True)
     assert off.resolve(4096, "seq") is None

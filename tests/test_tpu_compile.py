@@ -1,0 +1,85 @@
+"""The main path's Pallas kernels compile for a TPU v5e at real sizes.
+
+The TPU compiler is installed with JAX and compiles for a chip that is
+described, not attached, so these tests need no chip.  They catch what
+interpret mode cannot: block shapes the TPU lowering refuses, and
+kernels that use more VMEM than a core has.  Nothing runs; a compile
+that passes here is not a run on the chip.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.scoring import E_BINPACK
+from repro.kernels import node_score as ns
+from repro.kernels.wkv6 import wkv6_pallas
+
+NODE_BLOCK = ns.LANE * ns.BLOCK_ROWS
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler / topology support here
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without one; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _node_table(n_nodes, sharding):
+    """The five node columns as the ops wrapper pads them: (rows, LANE)."""
+    rows = -(-n_nodes // NODE_BLOCK) * NODE_BLOCK // ns.LANE
+    cols = (jnp.int32, jnp.int32, jnp.int32, jnp.float32, jnp.float32)
+    return [jax.ShapeDtypeStruct((rows, ns.LANE), dt, sharding=sharding)
+            for dt in cols]
+
+
+def _weights():
+    w = E_BINPACK
+    return dict(request=8, gpus_per_node=8, w_used=w.used, w_fit=w.fit,
+                w_group=w.group, w_topo=w.topo)
+
+
+@pytest.mark.parametrize("kernel", [ns.node_scores_pallas,
+                                    ns.node_scores_slots_pallas],
+                         ids=["scores", "scores_slots"])
+@pytest.mark.parametrize("n_nodes", [10_000, 1_000_000])
+def test_node_score_kernels_compile(one_chip, kernel, n_nodes):
+    compiled = kernel.lower(*_node_table(n_nodes, one_chip),
+                            **_weights()).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_wkv6_compiles_at_rwkv6_3b_widths(one_chip):
+    # rwkv6-3b: d_model 2560 = 40 heads x 64; train_4k sequence length.
+    B, T, H, n, tb = 1, 4096, 40, 64, 256
+    f32 = lambda shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                             sharding=one_chip)
+    streams = [f32((B, T, H, n)) for _ in range(4)]
+    compiled = wkv6_pallas.lower(*streams, f32((H, n)), f32((B, H, n, n)),
+                                 tb=tb).compile()
+    assert "tpu_custom_call" in compiled.as_text()
