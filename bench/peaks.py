@@ -1,0 +1,26 @@
+"""Published peaks of the chips the benchmark runs on, keyed by JAX's
+``device_kind``.
+
+Source: Google Cloud documentation, "TPU v5e" (system architecture):
+197 TFLOP/s bf16, 393 TOP/s int8, 16 GB HBM at 819 GB/s per chip.
+A kind that is not in the table is an error, never a default.
+"""
+
+from __future__ import annotations
+
+_V5E = {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12,
+        "hbm_bytes": 16e9}
+
+PEAKS = {
+    "TPU v5 lite": _V5E,
+    "TPU v5e": _V5E,
+}
+
+
+def peaks_for(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind "
+                       f"{device_kind!r}; add them to bench/peaks.py "
+                       f"with their source") from None
