@@ -1,18 +1,13 @@
-"""Observability benchmark: zero-cost detachment, bounded attach cost.
+"""Observability benchmark: zero-cost detachment, complete traces.
 
-Three gates, matching the telemetry subsystem's acceptance criteria:
+Two gates, matching the telemetry subsystem's acceptance criteria:
 
 1. **Byte-identity** — attaching a full :class:`repro.obs.Telemetry`
    (registry + tracing + audit) must not perturb the simulation: across
    a policy x strategy matrix, placements, metric reports and the raw
    sample series are identical to the untelemetered run.  Detached,
    every ``obs`` hook is a single ``is None`` branch.
-2. **Attached overhead** — with telemetry fully attached, the per-cycle
-   scheduling cost on a fragmented 10k-node cluster stays within **5%**
-   of the detached cycle.  Both arms are timed interleaved and compared
-   by the median of paired per-iteration deltas, so machine-load drift
-   and GC outliers cannot fake or mask an overhead.
-3. **Trace completeness** — on a seeded elastic run with node failures,
+2. **Trace completeness** — on a seeded elastic run with node failures,
    the emitted Chrome-trace has a span/instant for every lifecycle bus
    event: one ``job-<uid>`` B per SUBMIT, an E at every authoritative
    END, a ``NODE_FAIL`` instant per failure event and a ``reshape``
@@ -28,7 +23,6 @@ import argparse
 import dataclasses
 import os
 import sys
-import time
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -40,13 +34,12 @@ if __package__ in (None, ""):   # `python benchmarks/obs_bench.py`
 from benchmarks.common import (bench_seed, clone_jobs, scale_topology,
                                write_bench_json)  # noqa: E402
 from repro.core import (CheckpointModel, ClusterState, DynamicsConfig,
-                        ElasticManager, Job, JobKind, JobState,
+                        ElasticManager, Job, JobState,
                         NodeFailureInjector, QSCH, QSCHConfig,
                         QueuePolicy, QuotaManager, RSCH, RSCHConfig,
                         SimConfig, Simulator, SimResult, Strategy,
                         scaling_artifacts, spec_from_artifacts,
                         training_trace)  # noqa: E402
-from repro.core.topology import ClusterTopology  # noqa: E402
 from repro.obs import PID_JOBS, Telemetry  # noqa: E402
 
 
@@ -120,109 +113,7 @@ def identity_gate(seed: int, smoke: bool) -> Dict:
 
 
 # ----------------------------------------------------------------------
-# 2. Attached per-cycle overhead at 10k nodes
-# ----------------------------------------------------------------------
-def _fragmented_state(n_nodes: int, seed: int = 0) -> ClusterState:
-    """~60% of nodes partially busy (same shape as sched_scale_bench)."""
-    topo = ClusterTopology(
-        n_nodes=n_nodes, gpus_per_node=8, nodes_per_leaf=32,
-        leaves_per_spine=4, spines_per_superspine=4, nodes_per_hbd=32)
-    state = ClusterState.create(topo)
-    rng = np.random.default_rng(seed)
-    busy_nodes = rng.random(n_nodes) < 0.6
-    busy_count = rng.integers(1, 9, size=n_nodes)
-    for node in np.nonzero(busy_nodes)[0]:
-        state.gpu_busy[node, :busy_count[node]] = True
-    return state
-
-
-GANG_PODS = 64
-
-
-def _cycle_stack(n_nodes: int, seed: int):
-    """Production-default QSCH stack (incremental snapshots): every
-    cycle runs the complete snapshot -> admit -> filter -> score ->
-    select -> reserve -> bind pipeline for one 64-pod gang (the §3.4
-    hot path)."""
-    state = _fragmented_state(n_nodes, seed)
-    qm = QuotaManager({"t0": {0: 10**9}})
-    rsch = RSCH(state.topology,
-                RSCHConfig(train_strategy=Strategy.E_BINPACK))
-    qsch = QSCH(qm, rsch, QSCHConfig(policy=QueuePolicy.STRICT_FIFO))
-    return state, qsch
-
-
-def _one_cycle(state: ClusterState, qsch: QSCH, now: float):
-    """Time one bind cycle, then reset the cluster (untimed) so the
-    next iteration schedules against the exact same state."""
-    qsch.submit(Job(uid=1, tenant="t0", gpu_type=0, n_pods=GANG_PODS,
-                    gpus_per_pod=8, kind=JobKind.TRAIN))
-    t0 = time.perf_counter()
-    result = qsch.cycle(state, now)
-    dt = time.perf_counter() - t0
-    assert len(result.scheduled) == 1, \
-        f"bench gang must bind every cycle: {result}"
-    bound = result.scheduled[0]
-    picks = tuple((p.node, p.gpu_indices)
-                  for p in bound.placement.pods)
-    state.release(bound.uid)
-    qsch.running.clear()
-    qsch.quota.refund(bound)
-    return dt, picks
-
-
-def overhead_gate(seed: int, smoke: bool, n_nodes: int = 10_000) -> Dict:
-    repeats = 10 if smoke else 30
-    # ONE stack for both arms, with the obs facade toggled per
-    # iteration: the detached and attached cycles then share the exact
-    # same state, snapshot caches and memory layout, so the paired
-    # delta isolates the telemetry code itself.
-    state, qsch = _cycle_stack(n_nodes, seed)
-    tel = Telemetry()
-    tel.attach_qsch(qsch)
-    obs = qsch.obs
-
-    def set_obs(o) -> None:
-        qsch.obs = o
-        qsch.rsch.obs = o
-
-    set_obs(None)
-    _one_cycle(state, qsch, 0.0)                        # warm caches
-    set_obs(obs)
-    _one_cycle(state, qsch, 0.0)
-    t_det, t_att = [], []
-    for i in range(repeats * 2):
-        now = 30.0 * (i + 1)
-        set_obs(None)
-        dt, picks_det = _one_cycle(state, qsch, now)
-        t_det.append(dt)
-        set_obs(obs)
-        dt, picks_att = _one_cycle(state, qsch, now)
-        t_att.append(dt)
-        assert picks_det == picks_att, \
-            "attached arm diverged from the detached placements"
-    # Median of the PAIRED per-iteration deltas: each delta shares its
-    # iteration's ambient machine conditions, and the median discards
-    # GC/preemption outliers that a min-of-N across arms amplifies.
-    det = float(np.median(t_det))
-    att = det + float(np.median(np.subtract(t_att, t_det)))
-    overhead = att / det - 1.0
-    audited = len(tel.audit.bound())
-    print(f"--- overhead at {n_nodes} nodes ({GANG_PODS}-pod gang): "
-          f"detached {det * 1e3:.2f}ms attached {att * 1e3:.2f}ms "
-          f"({overhead:+.1%}, budget 5%); {audited} binds audited")
-    assert audited == repeats * 2 + 1, \
-        f"expected one audited decision per attached cycle, got {audited}"
-    assert overhead <= 0.05, (
-        f"attached telemetry cost {overhead:+.1%} per cycle at "
-        f"{n_nodes} nodes, budget is 5%")
-    return {"n_nodes": n_nodes, "gang_pods": GANG_PODS,
-            "detached_cycle_s": det, "attached_cycle_s": att,
-            "overhead": overhead}
-
-
-# ----------------------------------------------------------------------
-# 3. Trace completeness on a failing, reshaping cluster
+# 2. Trace completeness on a failing, reshaping cluster
 # ----------------------------------------------------------------------
 def _dynamic_workload(seed: int, smoke: bool) -> List[Job]:
     """Rigid fragmenters + elastic 128-GPU gangs on 512 GPUs: under
@@ -331,12 +222,10 @@ def main(argv=None) -> None:
     summary: Dict = {
         "seed": seed,
         "identity": identity_gate(seed, args.smoke),
-        "overhead": overhead_gate(seed, args.smoke),
         "trace": trace_gate(seed, args.smoke),
     }
     write_bench_json("obs", summary)
-    print(f"obs bench: all gates passed (attached overhead "
-          f"{summary['overhead']['overhead']:+.1%})")
+    print("obs bench: all gates passed")
 
 
 if __name__ == "__main__":

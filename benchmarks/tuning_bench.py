@@ -44,8 +44,7 @@ if __package__ in (None, ""):   # `python benchmarks/tuning_bench.py`
 
 from benchmarks.common import (bench_seed, clone_jobs, scale_topology,
                                write_bench_json)  # noqa: E402
-from benchmarks.obs_bench import (GANG_PODS, _cycle_stack,
-                                  placement_fingerprint,
+from benchmarks.obs_bench import (placement_fingerprint,
                                   sample_series)  # noqa: E402
 from repro.core import (ClusterState, Event, EventKind, FederatedCluster,
                         FederatedSimulator, HillClimbController, Job,
@@ -55,6 +54,7 @@ from repro.core import (ClusterState, Event, EventKind, FederatedCluster,
                         StarvationEscalator, Strategy, TuningManager,
                         make_member, training_trace,
                         waiting_percentile)  # noqa: E402
+from repro.core.topology import ClusterTopology  # noqa: E402
 
 CONTROL_PERIOD_S = 1800.0
 
@@ -368,6 +368,36 @@ def warm_start_gate(seed: int, smoke: bool) -> Dict:
 # ----------------------------------------------------------------------
 # 4. Attached per-cycle overhead at 10k nodes
 # ----------------------------------------------------------------------
+def _fragmented_state(n_nodes: int, seed: int = 0) -> ClusterState:
+    """~60% of nodes partially busy (same shape as sched_scale_bench)."""
+    topo = ClusterTopology(
+        n_nodes=n_nodes, gpus_per_node=8, nodes_per_leaf=32,
+        leaves_per_spine=4, spines_per_superspine=4, nodes_per_hbd=32)
+    state = ClusterState.create(topo)
+    rng = np.random.default_rng(seed)
+    busy_nodes = rng.random(n_nodes) < 0.6
+    busy_count = rng.integers(1, 9, size=n_nodes)
+    for node in np.nonzero(busy_nodes)[0]:
+        state.gpu_busy[node, :busy_count[node]] = True
+    return state
+
+
+GANG_PODS = 64
+
+
+def _cycle_stack(n_nodes: int, seed: int):
+    """Production-default QSCH stack (incremental snapshots): every
+    cycle runs the complete snapshot -> admit -> filter -> score ->
+    select -> reserve -> bind pipeline for one 64-pod gang (the §3.4
+    hot path)."""
+    state = _fragmented_state(n_nodes, seed)
+    qm = QuotaManager({"t0": {0: 10**9}})
+    rsch = RSCH(state.topology,
+                RSCHConfig(train_strategy=Strategy.E_BINPACK))
+    qsch = QSCH(qm, rsch, QSCHConfig(policy=QueuePolicy.STRICT_FIFO))
+    return state, qsch
+
+
 def _one_cycle_tuned(state: ClusterState, qsch: QSCH, now: float,
                      mgr: Optional[TuningManager], seq: int):
     """Time one bind cycle plus (when attached) the manager's full

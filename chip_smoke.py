@@ -141,20 +141,21 @@ def fail(msg: str) -> None:
 # Scheduler phases
 # ---------------------------------------------------------------------------
 def kernel_lowering(backend: str = "pallas") -> int:
-    """Lower the score+slots call as RSCH makes it; return the number of
-    Mosaic custom calls in the lowered program."""
-    import jax
+    """Lower the score+slots kernel on the tiles RSCH's call hands it;
+    return the number of Mosaic custom calls in the lowered program."""
     import jax.numpy as jnp
     from repro.core.scoring import E_BINPACK
-    from repro.kernels import ops
+    from repro.kernels import node_score, ops
 
     n = SCHED_NODES
     cols = (jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int32),
             jnp.ones(n, jnp.int32), jnp.zeros(n, jnp.float32),
             jnp.zeros(n, jnp.float32))
-    lowered = jax.jit(lambda *c: ops.node_scores_and_slots(
-        *c, request=8, gpus_per_node=8, weights=E_BINPACK,
-        backend=backend)).lower(*cols)
+    tiles, _ = ops.to_tiles(cols, n)
+    w = E_BINPACK
+    lowered = node_score.node_scores_slots_pallas.lower(
+        *tiles, request=8, gpus_per_node=8, w_used=w.used, w_fit=w.fit,
+        w_group=w.group, w_topo=w.topo, interpret=(backend != "pallas"))
     return lowered.as_text().count("tpu_custom_call")
 
 

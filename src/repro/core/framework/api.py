@@ -535,15 +535,33 @@ class ControllerPlugin(Plugin):
 _NULL_PHASE = contextlib.nullcontext()
 
 
-def obs_phase(obs, name: str):
+def obs_phase(obs, name: str, uid: Optional[int] = None):
     """Timed-phase context for an attached telemetry observer.
 
-    QSCH/RSCH wrap each pipeline stage (snapshot → queue-sort → filter
-    → score → reserve-permit → bind → preempt) in
+    QSCH, RSCH and the score call wrap each stage where its work happens
+    (``qsch-cycle`` ⊃ snapshot, queue-sort, ``rsch-schedule`` ⊃ filter,
+    ``group-choice``, score ⊃ ``score-upload`` / ``-launch`` / ``-wait``
+    / ``-fetch`` and ``slot-walk``, reserve-permit, bind, preempt; the
+    table is in docs/observability.md) in
     ``with obs_phase(self.obs, "..."):``; with ``obs is None`` (no
     telemetry attached) this returns a shared null context and the
-    stage runs untimed and unchanged."""
-    return _NULL_PHASE if obs is None else obs.phase(name)
+    stage runs untimed and unchanged.  ``uid`` names the job a span
+    works for; it reaches only observers that declare ``phase_uid``."""
+    if obs is None:
+        return _NULL_PHASE
+    if uid is not None and getattr(obs, "phase_uid", False):
+        return obs.phase(name, uid)
+    return obs.phase(name)
+
+
+def obs_count(obs, name: str, n: int) -> None:
+    """Add ``n`` to counter ``name`` of an attached observer; a no-op
+    with no observer, or with one that has no ``count``."""
+    if obs is None:
+        return
+    count = getattr(obs, "count", None)
+    if count is not None:
+        count(name, n)
 
 
 # ----------------------------------------------------------------------

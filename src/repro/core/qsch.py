@@ -186,6 +186,18 @@ class QSCH:
         if obs is not None:
             obs.cycle_begin(now)
         result = CycleResult()
+        ctx = None
+        try:
+            with obs_phase(obs, "qsch-cycle"):
+                ctx = self._cycle_body(state, now, result)
+            return result
+        finally:
+            if obs is not None and ctx is not None:
+                obs.cycle_end(result, ctx)
+
+    def _cycle_body(self, state: ClusterState, now: float,
+                    result: CycleResult) -> CycleContext:
+        obs = self.obs
         if self.pipeline is not None:
             self.pipeline.begin_cycle(state)
         with obs_phase(obs, "snapshot"):
@@ -222,13 +234,11 @@ class QSCH:
             if self.elastic is not None:
                 with obs_phase(obs, "elastic"):
                     self.elastic.grow_pass(ctx)
-            return result
+            return ctx
         finally:
             if self.pipeline is not None:
                 self.pipeline.end_cycle(state, now)
             self._working_snap = None
-            if obs is not None:
-                obs.cycle_end(result, ctx)
 
     def sync_health(self, state: ClusterState, nodes) -> None:
         """Mirror an external health/drain mutation onto the scheduler's

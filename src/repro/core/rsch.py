@@ -253,11 +253,12 @@ class RSCH:
         if obs is not None and obs.audit_on:
             capture = {"profile": profile.name, "passes": []}
         result = ScheduleResult(None, "empty placement plan")
-        for pass_ in profile.plan(job, snap):
-            result = self._run_pass(job, snap, pass_, profile, ctx,
-                                    capture)
-            if result.placement is not None:
-                break
+        with obs_phase(obs, "rsch-schedule", job.uid):
+            for pass_ in profile.plan(job, snap):
+                result = self._run_pass(job, snap, pass_, profile, ctx,
+                                        capture)
+                if result.placement is not None:
+                    break
         result.audit = capture
         return result
 
@@ -414,29 +415,32 @@ class RSCH:
                       and capture is None)
 
         # --- Level 1: NodeNetGroup preselection (§3.4.2) ---------------
-        gt = int(job.gpu_type)
-        if use_subset:
-            pod_slots = None
-            group_slots = self._group_slots_cached(snap, gt, pass_.zone,
-                                                   job.gpus_per_pod)
-            group_free = self._group_free_cached(snap, gt, pass_.zone)
-            group_used_i = self._group_used_cached(snap, gt, pass_.zone)
-        else:
-            pod_slots = np.where(pool, snap.free_gpus // job.gpus_per_pod,
-                                 0)
-            group_slots = group_free = group_used_i = None
-        group_term = self._group_score_terms(job, snap, pool, pass_, ctx)
-        selected_groups = self._preselect_groups(
-            job, snap, pool, pod_slots, pass_.enhanced, pass_.spread,
-            group_term, group_slots=group_slots, group_free=group_free,
-            group_used=group_used_i)
-        if selected_groups is None:
-            return fail("no NodeNetGroup set satisfies job")
-        # One gather resolves both group membership and the per-node
-        # anchor-group preference (rank table over groups -> node axis).
-        group_pref = np.zeros(topo.n_leaf_groups, dtype=np.float32)
-        for rank, g in enumerate(selected_groups):
-            group_pref[g] = 1.0 / (1.0 + rank)
+        with obs_phase(obs, "group-choice"):
+            gt = int(job.gpu_type)
+            if use_subset:
+                pod_slots = None
+                group_slots = self._group_slots_cached(
+                    snap, gt, pass_.zone, job.gpus_per_pod)
+                group_free = self._group_free_cached(snap, gt, pass_.zone)
+                group_used_i = self._group_used_cached(snap, gt,
+                                                       pass_.zone)
+            else:
+                pod_slots = np.where(pool,
+                                     snap.free_gpus // job.gpus_per_pod, 0)
+                group_slots = group_free = group_used_i = None
+            group_term = self._group_score_terms(job, snap, pool, pass_,
+                                                 ctx)
+            selected_groups = self._preselect_groups(
+                job, snap, pool, pod_slots, pass_.enhanced, pass_.spread,
+                group_term, group_slots=group_slots, group_free=group_free,
+                group_used=group_used_i)
+            if selected_groups is None:
+                return fail("no NodeNetGroup set satisfies job")
+            # One gather resolves both group membership and the per-node
+            # anchor-group preference (rank table over groups -> nodes).
+            group_pref = np.zeros(topo.n_leaf_groups, dtype=np.float32)
+            for rank, g in enumerate(selected_groups):
+                group_pref[g] = 1.0 / (1.0 + rank)
 
         # --- Level 2: node selection within selected groups ------------
         # Score chain: fused weights go through the shared kernel pass;
@@ -607,8 +611,8 @@ class RSCH:
         lifted terms sum to the captured fused score (float32 rounding
         aside).  The audit layer does the term arithmetic and the
         per-node pivot lazily, on first ``decision.passes`` read —
-        this function is on the bind hot path (≤5% attached-overhead
-        budget in ``benchmarks/obs_bench.py``)."""
+        this function is on the bind hot path, whose attached cost
+        PERF.md records from the chip as traced per-layer deltas."""
         idx = np.fromiter(dict.fromkeys(nodes), dtype=np.intp)
         # Capture = gathers only.  Small per-node copies of the fused
         # kernel's inputs (snapshot rows mutate after the bind; the
@@ -672,7 +676,7 @@ class RSCH:
                 snap.free_gpus, snap.used_gpus, mask.astype(np.int32),
                 gload_nodes, topo_pref, request=job.gpus_per_pod,
                 gpus_per_node=self.topology.gpus_per_node, weights=weights,
-                backend=backend)
+                backend=backend, obs=self.obs)
             scores = np.asarray(s)
             slots = np.asarray(sl).astype(np.int64)
         if extra is not None:
@@ -681,10 +685,11 @@ class RSCH:
             # By reference — the audit breakdown reads a handful of
             # entries; no copy on the scheduling path.
             score_out["scores"] = scores
-        return select_gang_slots(
-            scores, snap.free_gpus, job.gpus_per_pod, job.n_pods,
-            fit_weight=weights.fit, colocate_bonus=colocate, slots=slots,
-            engine=self.config.slot_engine)
+        with obs_phase(self.obs, "slot-walk"):
+            return select_gang_slots(
+                scores, snap.free_gpus, job.gpus_per_pod, job.n_pods,
+                fit_weight=weights.fit, colocate_bonus=colocate,
+                slots=slots, engine=self.config.slot_engine)
 
     def _select_nodes_subset(self, job: Job, snap: Snapshot,
                              pool: np.ndarray, selected_groups: List[int],
@@ -713,10 +718,11 @@ class RSCH:
             scores = np.where(scores > NEG_INF, scores + ex, scores)
         slots = np.where(mask, free_sub // job.gpus_per_pod,
                          0).astype(np.int64)
-        order = select_gang_slots(
-            scores, free_sub, job.gpus_per_pod, job.n_pods,
-            fit_weight=weights.fit, colocate_bonus=colocate, slots=slots,
-            engine=self.config.slot_engine)
+        with obs_phase(self.obs, "slot-walk"):
+            order = select_gang_slots(
+                scores, free_sub, job.gpus_per_pod, job.n_pods,
+                fit_weight=weights.fit, colocate_bonus=colocate,
+                slots=slots, engine=self.config.slot_engine)
         if order is None:
             return None
         return [int(sub[p]) for p in order]

@@ -10,20 +10,29 @@ Backend selection:
 * ``backend="pallas"``       — compiled Pallas kernel (TPU target);
 * ``backend="interpret"``    — Pallas in interpret mode (CPU validation);
 * ``backend="ref"``          — jnp oracle.
+
+``node_scores_and_slots`` is the call RSCH makes once per placement
+attempt.  With an ``obs`` observer attached it times its four parts as
+phases (``score-upload``, ``score-launch``, ``score-wait``,
+``score-fetch``) and counts ``score-h2d-bytes`` and ``score-d2h-bytes``.
 """
 
 from __future__ import annotations
 
-from typing import Optional
+from typing import Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.framework.api import obs_count, obs_phase
 from ..core.scoring import ScoreWeights
 from . import node_score as _ns
 from .ref import node_scores_ref
 
 _ROW = _ns.LANE * _ns.BLOCK_ROWS
+# Padding of (free, used, mask, group_load, topo_pref): mask 0 rows.
+_FILLS = (0, 0, 0, 0.0, 0.0)
 
 
 def _pad_to(x: jnp.ndarray, n: int, fill=0) -> jnp.ndarray:
@@ -34,6 +43,26 @@ def _pad_to(x: jnp.ndarray, n: int, fill=0) -> jnp.ndarray:
         [x, jnp.full((pad,), fill, dtype=x.dtype)], axis=0)
 
 
+def to_tiles(cols: Sequence, n: int) -> tuple:
+    """The five node columns as device arrays in the kernel's
+    ``(rows, LANE)`` tiling, padded to whole blocks.  Returns
+    ``(tiles, padded)``, ``padded`` being the padded node count."""
+    padded = max(_ROW, -(-n // _ROW) * _ROW)
+    rows = padded // _ns.LANE
+    tiles = [_pad_to(jnp.asarray(a), padded, fill).reshape(rows, _ns.LANE)
+             for a, fill in zip(cols, _FILLS)]
+    return tiles, padded
+
+
+def _kernel_kw(weights, request, gpus_per_node, w_used, w_fit, w_group,
+               w_topo) -> dict:
+    if weights is not None:
+        w_used, w_fit = weights.used, weights.fit
+        w_group, w_topo = weights.group, weights.topo
+    return dict(request=request, gpus_per_node=gpus_per_node,
+                w_used=w_used, w_fit=w_fit, w_group=w_group, w_topo=w_topo)
+
+
 def node_scores(free, used, mask, group_load, topo_pref, *, request: int,
                 gpus_per_node: int,
                 weights: Optional[ScoreWeights] = None,
@@ -42,13 +71,10 @@ def node_scores(free, used, mask, group_load, topo_pref, *, request: int,
                 backend: str = "ref") -> jnp.ndarray:
     """Fused filter+score over an n-node table; returns (n,) f32 scores
     with ``-inf`` at invalid nodes."""
-    if weights is not None:
-        w_used, w_fit = weights.used, weights.fit
-        w_group, w_topo = weights.group, weights.topo
+    kw = _kernel_kw(weights, request, gpus_per_node, w_used, w_fit,
+                    w_group, w_topo)
     free = jnp.asarray(free)
     n = free.shape[0]
-    kw = dict(request=request, gpus_per_node=gpus_per_node, w_used=w_used,
-              w_fit=w_fit, w_group=w_group, w_topo=w_topo)
 
     if backend == "ref":
         return node_scores_ref(free, jnp.asarray(used), jnp.asarray(mask),
@@ -57,15 +83,9 @@ def node_scores(free, used, mask, group_load, topo_pref, *, request: int,
     if backend not in ("pallas", "interpret"):
         raise ValueError(f"unknown backend {backend!r}")
 
-    padded = max(_ROW, -(-n // _ROW) * _ROW)
-    rows = padded // _ns.LANE
-    args2d = []
-    for arr, fill in ((free, 0), (used, 0), (mask, 0),
-                      (group_load, 0.0), (topo_pref, 0.0)):
-        a = _pad_to(jnp.asarray(arr), padded, fill)
-        args2d.append(a.reshape(rows, _ns.LANE))
+    tiles, padded = to_tiles((free, used, mask, group_load, topo_pref), n)
     out = _ns.node_scores_pallas(
-        *args2d, interpret=(backend == "interpret"), **kw)
+        *tiles, interpret=(backend == "interpret"), **kw)
     return out.reshape(padded)[:n]
 
 
@@ -74,40 +94,53 @@ def node_scores_and_slots(free, used, mask, group_load, topo_pref, *,
                           weights: Optional[ScoreWeights] = None,
                           w_used: float = 0.0, w_fit: float = 0.0,
                           w_group: float = 0.0, w_topo: float = 0.0,
-                          backend: str = "ref"):
+                          backend: str = "ref", obs=None):
     """Fused (scores, pod_slots) pass for batched gang placement.
 
     One sweep over the node table yields both the per-node score and the
     number of pod slots ``floor(free / request)`` each node contributes
     (0 where invalid), feeding the whole-gang top-k slot selection in
     :func:`repro.core.scoring.select_gang_slots`.
-    """
-    if weights is not None:
-        w_used, w_fit = weights.used, weights.fit
-        w_group, w_topo = weights.group, weights.topo
-    free = jnp.asarray(free)
-    n = free.shape[0]
-    kw = dict(request=request, gpus_per_node=gpus_per_node, w_used=w_used,
-              w_fit=w_fit, w_group=w_group, w_topo=w_topo)
 
+    The ``pallas`` and ``interpret`` backends return host numpy arrays:
+    the call uploads and pads the columns (``score-upload``), launches
+    the kernel and the reshape/slice of its outputs (``score-launch``),
+    and copies both outputs to the host (``score-fetch``), each a phase
+    of ``obs`` when one is attached.  With an observer it first waits
+    for the device apart (``score-wait``); without one the copies wait.
+    The ``ref`` backend returns device arrays, untimed.
+    """
+    kw = _kernel_kw(weights, request, gpus_per_node, w_used, w_fit,
+                    w_group, w_topo)
     if backend == "ref":
         from .ref import node_scores_slots_ref
         return node_scores_slots_ref(
-            free, jnp.asarray(used), jnp.asarray(mask),
+            jnp.asarray(free), jnp.asarray(used), jnp.asarray(mask),
             jnp.asarray(group_load), jnp.asarray(topo_pref), **kw)
     if backend not in ("pallas", "interpret"):
         raise ValueError(f"unknown backend {backend!r}")
 
-    padded = max(_ROW, -(-n // _ROW) * _ROW)
-    rows = padded // _ns.LANE
-    args2d = []
-    for arr, fill in ((free, 0), (used, 0), (mask, 0),
-                      (group_load, 0.0), (topo_pref, 0.0)):
-        a = _pad_to(jnp.asarray(arr), padded, fill)
-        args2d.append(a.reshape(rows, _ns.LANE))
-    scores, slots = _ns.node_scores_slots_pallas(
-        *args2d, interpret=(backend == "interpret"), **kw)
-    return scores.reshape(padded)[:n], slots.reshape(padded)[:n]
+    n = np.shape(free)[0]
+    with obs_phase(obs, "score-upload"):
+        tiles, padded = to_tiles(
+            (free, used, mask, group_load, topo_pref), n)
+    with obs_phase(obs, "score-launch"):
+        scores, slots = _ns.node_scores_slots_pallas(
+            *tiles, interpret=(backend == "interpret"), **kw)
+        scores = scores.reshape(padded)[:n]
+        slots = slots.reshape(padded)[:n]
+    if obs is not None:
+        # Only under an observer: the block is one more host-device round
+        # trip (~0.4 ms per call on a TPU v5e, PERF.md) that the copies
+        # below otherwise fold into their own wait.
+        with obs_phase(obs, "score-wait"):
+            jax.block_until_ready((scores, slots))
+    with obs_phase(obs, "score-fetch"):
+        scores, slots = np.asarray(scores), np.asarray(slots)
+    if obs is not None:
+        obs_count(obs, "score-h2d-bytes", sum(t.nbytes for t in tiles))
+        obs_count(obs, "score-d2h-bytes", scores.nbytes + slots.nbytes)
+    return scores, slots
 
 
 def gang_slot_prefilter(scores, slots, n_pods: int) -> np.ndarray:

@@ -7,8 +7,9 @@ pillars, one attach point:
 * :mod:`repro.obs.registry`  — Prometheus-style metrics with
   ring-buffered time series and text/JSON exposition;
 * :mod:`repro.obs.trace`     — Chrome trace-event tracer (Perfetto):
-  wall-clock cycle spans with pipeline-phase children, sim-time job
-  lifecycle spans, cluster instants;
+  wall-clock phase spans at their real offsets (also
+  ``jax.profiler.TraceAnnotation``s on the profiler's clock), sim-time
+  job lifecycle spans, cluster instants, byte counters;
 * :mod:`repro.obs.audit`     — kube-scheduler-style decision audit
   (filter eliminations, per-ScorePlugin breakdown of bound nodes,
   preemption rationale) behind the ObserverPlugin extension point;
@@ -17,8 +18,9 @@ pillars, one attach point:
 
 Telemetry is strictly opt-in: with nothing attached, every core hook
 is a ``None`` check and scheduling output is byte-identical to an
-untelemetered build (``benchmarks/obs_bench.py`` gates this, plus the
-≤5% attached per-cycle overhead budget).
+untelemetered build (``benchmarks/obs_bench.py`` gates this).  What
+the attached spans cost is measured on the chip: PERF.md records the
+traced per-layer deltas against the parent commit.
 
 See ``docs/observability.md``.
 """

@@ -86,8 +86,8 @@ class PlacementDecision:
 
     Not a dataclass: ``passes`` lifts the raw RSCH capture into typed
     :class:`PassAudit` records lazily, on first read — the bind hot
-    path only stashes a reference (the ≤5% attached-overhead budget in
-    ``benchmarks/obs_bench.py`` counts on this)."""
+    path only stashes a reference (the attached cost PERF.md records
+    from the chip, as traced per-layer deltas, counts on this)."""
 
     __slots__ = ("uid", "tenant", "kind", "outcome", "reason", "t",
                  "profile", "member", "_nodes", "_placement",

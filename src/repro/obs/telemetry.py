@@ -16,8 +16,9 @@ a simulator with one call::
 MetricsRecorder and installs the EventBus tap — the *only* coupling the
 core has to this package.  With no telemetry attached every ``obs`` is
 ``None`` and the pipeline is byte-identical to an untelemetered build
-(gated in ``benchmarks/obs_bench.py``); attached overhead is budgeted
-at ≤5% per cycle at 10k nodes by the same benchmark.
+(gated in ``benchmarks/obs_bench.py``); what the attached spans cost is
+read on the chip, as the change in the benchmark's traced per-layer
+numbers against the parent commit (PERF.md).
 
 A federation attaches one Telemetry to every member simulator with a
 *scope*::
@@ -30,8 +31,11 @@ scheduler trace lane per member, and stamp decisions with the member
 name.
 
 Time domains: the registry clock and job/cluster trace events run on
-**simulated** time; cycle spans are **wall-clock** (that is what "where
-does scheduling CPU go" means).  See :mod:`repro.obs.trace`.
+**simulated** time; phase spans are **wall-clock** (that is what "where
+does scheduling CPU go" means), at their real offsets.  Every phase is
+also a ``jax.profiler.TraceAnnotation``, so under ``jax.profiler.trace``
+the program's phases sit on the profiler's clock beside the device's
+operations.  See :mod:`repro.obs.trace`.
 """
 
 from __future__ import annotations
@@ -41,6 +45,8 @@ import json
 import time
 from typing import Dict, List, Optional, Sequence
 
+from jax.profiler import TraceAnnotation
+
 from ..core.events import EventKind
 from ..launch.combo_cache import cache_stats
 from .audit import DecisionAudit, PreemptionRecord, build_decision
@@ -48,6 +54,9 @@ from .registry import MetricRegistry
 from .trace import PID_CLUSTER, PID_JOBS, PID_SCHED, Tracer
 
 __all__ = ["Telemetry", "CycleSpan", "JobRecord"]
+
+#: The phase QSCH wraps around a whole cycle: Telemetry's cycle span.
+CYCLE_PHASE = "qsch-cycle"
 
 #: Histogram buckets for per-cycle wall time (seconds).
 _CYCLE_BUCKETS = (1e-5, 3e-5, 1e-4, 3e-4, 1e-3, 3e-3, 1e-2, 3e-2, 0.1,
@@ -97,27 +106,41 @@ class JobRecord:
 
 
 class _PhaseTimer:
-    """Context manager accumulating one pipeline phase's wall time."""
+    """Context manager for one pipeline phase: a profiler annotation, a
+    span on the scheduler lane at its real start and end, and its wall
+    time added to the phase totals.  ``uid`` (the job a span works for)
+    goes into both the annotation and the span's args."""
 
-    __slots__ = ("tel", "scope", "name", "_t0")
+    __slots__ = ("tel", "scope", "name", "uid", "_t0", "_ann")
 
     def __init__(self, tel: "Telemetry", scope: Optional[str],
                  name: str) -> None:
         self.tel = tel
         self.scope = scope
         self.name = name
+        self.uid: Optional[int] = None
+        self._ann = None
 
     def __enter__(self) -> "_PhaseTimer":
+        uid = self.uid
+        self._ann = (TraceAnnotation(self.name) if uid is None
+                     else TraceAnnotation(self.name, uid=uid))
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
+        self.tel._phase_begin(self.scope, self.name, self._t0, uid)
         return self
 
     def __exit__(self, *exc) -> None:
-        self.tel._phase_done(self.scope, self.name,
-                             time.perf_counter() - self._t0)
+        t1 = time.perf_counter()
+        self.tel._phase_end(self.scope, self.name, self._t0, t1)
+        self._ann.__exit__(*exc)
+        self._ann = None
 
 
 class _ScopedTelemetry:
     """Thin per-member adapter: the same obs interface, scope-bound."""
+
+    phase_uid = True
 
     def __init__(self, tel: "Telemetry", scope: str) -> None:
         self._tel = tel
@@ -127,8 +150,11 @@ class _ScopedTelemetry:
     def audit_on(self) -> bool:
         return self._tel.audit_on
 
-    def phase(self, name: str) -> _PhaseTimer:
-        return self._tel._timer(self._scope, name)
+    def phase(self, name: str, uid: Optional[int] = None) -> _PhaseTimer:
+        return self._tel._timer(self._scope, name, uid)
+
+    def count(self, name: str, n: int) -> None:
+        self._tel.count(name, n, scope=self._scope)
 
     def cycle_begin(self, now: float) -> None:
         self._tel.cycle_begin(now, scope=self._scope)
@@ -177,6 +203,9 @@ class Telemetry:
     behind the built-in audit.
     """
 
+    #: ``obs_phase`` hands this observer the job uid of a span.
+    phase_uid = True
+
     def __init__(self, registry: bool = True, tracing: bool = True,
                  audit: bool = True, observers: Sequence = (),
                  ring: int = 512, max_trace_events: int = 500_000,
@@ -197,6 +226,7 @@ class Telemetry:
         self._cycles: Dict[Optional[str], Dict] = {}
         self._scope_tids: Dict[Optional[str], int] = {}
         self.phase_totals: Dict[str, float] = {}
+        self.counts: Dict[tuple, int] = {}
         self.jobs: Dict[tuple, JobRecord] = {}
         self.event_counts: Dict[str, int] = {}
         self._attached: List = []
@@ -267,8 +297,10 @@ class Telemetry:
                 self.tracer.metadata(PID_CLUSTER, "cluster (sim time)")
         return tid
 
-    def _wall_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    def _wall_us(self, t: Optional[float] = None) -> float:
+        if t is None:
+            t = time.perf_counter()
+        return (t - self._t0) * 1e6
 
     def _job_rec(self, job, scope: Optional[str]) -> JobRecord:
         key = (scope, job.uid)
@@ -280,10 +312,11 @@ class Telemetry:
         return rec
 
     # -- phases / cycles -----------------------------------------------
-    def phase(self, name: str) -> _PhaseTimer:
-        return self._timer(None, name)
+    def phase(self, name: str, uid: Optional[int] = None) -> _PhaseTimer:
+        return self._timer(None, name, uid)
 
-    def _timer(self, scope: Optional[str], name: str) -> _PhaseTimer:
+    def _timer(self, scope: Optional[str], name: str,
+               uid: Optional[int] = None) -> _PhaseTimer:
         """Interned per (scope, name): phases are non-reentrant and the
         pipeline enters several per cycle — reusing the context manager
         keeps the attached hot path allocation-free."""
@@ -291,27 +324,60 @@ class Telemetry:
         if tmr is None:
             tmr = self._timers[(scope, name)] = _PhaseTimer(self, scope,
                                                             name)
+        tmr.uid = uid
         return tmr
 
-    def _phase_done(self, scope: Optional[str], name: str,
-                    dt: float) -> None:
+    def _phase_begin(self, scope: Optional[str], name: str, t0: float,
+                     uid: Optional[int]) -> None:
+        tr = self.tracer
+        if tr is not None:
+            tr.begin(name, self._wall_us(t0), PID_SCHED,
+                     self._sched_tid(scope),
+                     args=None if uid is None else {"uid": uid})
+
+    def _phase_end(self, scope: Optional[str], name: str, t0: float,
+                   t1: float) -> None:
+        dt = t1 - t0
         self.phase_totals[name] = self.phase_totals.get(name, 0.0) + dt
         cyc = self._cycles.get(scope)
         if cyc is not None:
             ph = cyc["phases"]
             ph[name] = ph.get(name, 0.0) + dt
+        tr = self.tracer
+        if tr is None:
+            return
+        if name == CYCLE_PHASE and cyc is not None:
+            # cycle_end closes the cycle span, with the cycle's results.
+            cyc["end_us"] = self._wall_us(t1)
+        else:
+            tr.end(name, self._wall_us(t1), PID_SCHED,
+                   self._sched_tid(scope))
+
+    def count(self, name: str, n: int,
+              scope: Optional[str] = None) -> None:
+        """Add ``n`` to counter ``name`` (``obs_count``): a registry
+        counter ``kant_<name>_total`` and a trace counter event with the
+        running total."""
+        key = (scope, name)
+        total = self.counts[key] = self.counts.get(key, 0) + n
+        if self.registry is not None:
+            self.registry.counter(
+                "kant_" + name.replace("-", "_") + "_total",
+                f"{name} counted by the program").inc(
+                n, **self._labels(scope))
+        if self.tracer is not None:
+            self.tracer.counter(name, self._wall_us(), PID_SCHED,
+                                self._sched_tid(scope), {name: total})
 
     def cycle_begin(self, now: float, scope: Optional[str] = None) -> None:
         self._simclock = float(now)
-        self._cycles[scope] = {"t": float(now),
-                               "wall0": time.perf_counter(),
-                               "phases": {}}
+        self._cycles[scope] = {"t": float(now), "phases": {}}
 
     def cycle_end(self, result, ctx, scope: Optional[str] = None) -> None:
         cyc = self._cycles.pop(scope, None)
         if cyc is None:
             return
-        wall = time.perf_counter() - cyc["wall0"]
+        wall = cyc["phases"].get(CYCLE_PHASE, 0.0)
         span = CycleSpan(t=cyc["t"], wall_s=wall, phases=cyc["phases"],
                          scope=scope, result=result)
         reg = self.registry
@@ -337,21 +403,11 @@ class Telemetry:
                           "wall-clock cycle duration",
                           buckets=_CYCLE_BUCKETS).observe(wall, **lbl)
         tr = self.tracer
-        if tr is not None:
-            tid = self._sched_tid(scope)
-            end_us = self._wall_us()
-            start_us = end_us - wall * 1e6
-            tr.begin("cycle", start_us, PID_SCHED, tid,
-                     args={"t_sim": cyc["t"]})
-            # The measured phases are re-laid sequentially inside the
-            # cycle span (their true offsets are not recorded; only the
-            # durations are) — documented in docs/observability.md.
-            ts = start_us
-            for name, dur in cyc["phases"].items():
-                tr.span(name, ts, dur * 1e6, PID_SCHED, tid)
-                ts += dur * 1e6
-            tr.end("cycle", end_us, PID_SCHED, tid,
-                   args={"scheduled": len(result.scheduled),
+        if tr is not None and "end_us" in cyc:
+            tr.end(CYCLE_PHASE, cyc["end_us"], PID_SCHED,
+                   self._sched_tid(scope),
+                   args={"t_sim": cyc["t"],
+                         "scheduled": len(result.scheduled),
                          "preempted": len(result.preempted),
                          "requeues": result.requeues})
         for ob in self.observers:

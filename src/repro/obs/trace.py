@@ -3,21 +3,25 @@
 Emits the `trace-event format`__ consumed by ``chrome://tracing`` and
 https://ui.perfetto.dev: a flat list of events with ``ph`` (phase),
 ``ts`` (microseconds), ``pid``/``tid`` lanes and free-form ``args``.
-Only four phases are used:
+Only five phases are used:
 
 * ``B``/``E`` — begin/end of a duration span (always balanced per
   ``(pid, tid)`` lane; asserted in ``tests/test_obs.py``);
 * ``i`` — an instant event (failures, preemptions, reshapes);
+* ``C`` — a counter's running total (``obs_count``: bytes to and from
+  the device per score call);
 * ``M`` — metadata naming the process/thread lanes.
 
 __ https://docs.google.com/document/d/1CvAClvFfyA5R-PhYUmn5OOQtYMH4h6I0nSsKchNAySU
 
 The telemetry layer maps the two time domains onto separate pids:
 
-* ``PID_SCHED`` — *wall-clock* scheduler spans: one span per QSCH
-  cycle with synthesized sequential child spans for the measured
-  pipeline phases (snapshot → queue-sort → filter → score →
-  reserve-permit → bind → preempt);
+* ``PID_SCHED`` — *wall-clock* scheduler spans: every phase the
+  program enters (``qsch-cycle`` ⊃ snapshot, queue-sort,
+  ``rsch-schedule`` ⊃ filter, ``group-choice``, score ⊃ ``score-*`` and
+  ``slot-walk``, reserve-permit, bind, ...) at its real start and end,
+  nested in its real parent; the same phases are
+  ``jax.profiler.TraceAnnotation``s on the profiler's clock;
 * ``PID_JOBS`` — *simulated-time* job lifecycle spans: SUBMIT opens,
   END closes, with bind / interrupt / reshape instants inside;
 * ``PID_CLUSTER`` — simulated-time cluster events (failures, drains,
@@ -44,8 +48,8 @@ class Tracer:
 
     Events are stored as compact ``(ph, name, ts, pid, tid, args)``
     tuples and materialized into trace-event dicts only at export —
-    emission sits on the scheduler's per-cycle hot path (the ≤5%
-    attached-overhead budget in ``benchmarks/obs_bench.py``)."""
+    emission sits on the scheduler's per-phase hot path, whose attached
+    cost PERF.md records from the chip."""
 
     def __init__(self, max_events: int = 500_000) -> None:
         self.events: List[tuple] = []
@@ -93,18 +97,10 @@ class Tracer:
                 args: Optional[Dict] = None) -> None:
         self._emit(("i", name, ts_us, pid, tid, args))
 
-    def span(self, name: str, ts_us: float, dur_us: float, pid: int,
-             tid: int, args: Optional[Dict] = None) -> None:
-        """A closed span as a balanced B/E pair.
-
-        Balanced by construction, so it skips the ``_open`` stack
-        entirely — the per-cycle phase spans go through here."""
-        ev = self.events
-        if len(ev) + 2 > self.max_events:
-            self.dropped += 2
-            return
-        ev.append(("B", name, ts_us, pid, tid, None))
-        ev.append(("E", name, ts_us + max(0.0, dur_us), pid, tid, args))
+    def counter(self, name: str, ts_us: float, pid: int, tid: int,
+                values: Dict[str, float]) -> None:
+        """A counter sample: ``values`` maps series to running totals."""
+        self._emit(("C", name, ts_us, pid, tid, values))
 
     # -- lifecycle -----------------------------------------------------
     def open_spans(self) -> Dict[tuple, List[str]]:

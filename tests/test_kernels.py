@@ -84,6 +84,37 @@ def test_scores_and_slots_fused_pass(backend, n):
     np.testing.assert_array_equal(np.asarray(slots), want_slots)
 
 
+@pytest.mark.parametrize("counts", [True, False])
+def test_scores_and_slots_phases_under_observer(counts):
+    """With an observer attached the interpret backend enters its four
+    phases once each, in order, counts the bytes each way (where the
+    observer counts), and returns the same numpy arrays as untraced."""
+    from conftest import PhaseLog
+    from repro.kernels.ops import node_scores_and_slots
+    n = 1000
+    cols = _table(np.random.default_rng(5), n)
+    kw = dict(request=4, gpus_per_node=8, weights=E_BINPACK,
+              backend="interpret")
+    log = PhaseLog(counts=counts)
+    scores, slots = node_scores_and_slots(*cols, obs=log, **kw)
+    parts = ["score-upload", "score-launch", "score-wait", "score-fetch"]
+    assert log.events == [(e, p) for p in parts for e in ("enter", "exit")]
+    assert isinstance(scores, np.ndarray) and isinstance(slots, np.ndarray)
+    plain_scores, plain_slots = node_scores_and_slots(*cols, **kw)
+    np.testing.assert_array_equal(scores, plain_scores)
+    np.testing.assert_array_equal(slots, plain_slots)
+    free, used, mask, gl, tp = cols
+    want = node_scores_np(free, used, mask, gl, tp, 4, 8, E_BINPACK)
+    np.testing.assert_allclose(scores, want, rtol=1e-6)
+    np.testing.assert_array_equal(slots,
+                                  np.where(want > NEG_INF, free // 4, 0))
+    padded = 8192                     # one block of 64 x 128 nodes
+    want_counts = {
+        "score-h2d-bytes": padded * sum(c.dtype.itemsize for c in cols),
+        "score-d2h-bytes": n * (4 + 4)} if counts else {}
+    assert log.counted == want_counts
+
+
 def test_no_valid_node_returns_minus_one():
     free = np.zeros(64, np.int32)
     used = np.full(64, 8, np.int32)

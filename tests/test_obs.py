@@ -132,7 +132,8 @@ def test_trace_event_schema_and_balance():
     tr = Tracer()
     tr.metadata(PID_SCHED, "scheduler (wall clock)")
     tr.begin("cycle", 0.0, PID_SCHED, 0, args={"t_sim": 0.0})
-    tr.span("filter", 1.0, 5.0, PID_SCHED, 0)
+    tr.begin("filter", 1.0, PID_SCHED, 0)
+    tr.end("filter", 6.0, PID_SCHED, 0)
     tr.instant("NODE_FAIL", 3.0, PID_SCHED, 0, args={"node": 4})
     tr.end("cycle", 10.0, PID_SCHED, 0)
     doc = tr.to_json()
@@ -166,9 +167,10 @@ def test_trace_event_cap_counts_drops():
     tr = Tracer(max_events=3)
     tr.instant("a", 0.0, PID_SCHED, 0)
     tr.instant("b", 1.0, PID_SCHED, 0)
-    tr.span("s", 2.0, 1.0, PID_SCHED, 0)   # needs 2 slots, only 1 left
-    assert tr.dropped == 2
-    assert len(tr.to_json()["traceEvents"]) == 2
+    tr.begin("s", 2.0, PID_SCHED, 0)       # takes the last slot
+    tr.end("s", 3.0, PID_SCHED, 0)
+    assert tr.dropped == 1
+    assert len(tr.to_json()["traceEvents"]) == 3
 
 
 # ----------------------------------------------------------------------
@@ -327,6 +329,89 @@ def test_job_spans_cover_run_and_lanes_balance():
         if j.start_time is not None:
             assert recs[j.uid]["first_start"] == j.start_time
             assert recs[j.uid]["wait_s"] == j.start_time - j.submit_time
+
+
+def _sched_spans(events):
+    """(name, begin, end, depth, args) per span of the scheduler lane,
+    parsed with a stack: a span that closes out of order fails."""
+    stack, spans = [], []
+    for e in events:
+        if e["pid"] != PID_SCHED or e["ph"] not in "BE":
+            continue
+        if e["ph"] == "B":
+            stack.append(e)
+            continue
+        b = stack.pop()
+        assert b["name"] == e["name"], "spans overlap partially"
+        args = dict(b.get("args") or {}, **(e.get("args") or {}))
+        spans.append((b["name"], b["ts"], e["ts"], len(stack), args))
+    assert not stack
+    return spans
+
+
+def test_trace_phases_nest_in_their_cycle_at_real_offsets():
+    """Every phase span lies inside its ``qsch-cycle`` span, spans nest
+    without partial overlap, and each sits at its own start and end:
+    RSCH's phases nest inside ``rsch-schedule``, which no sequential
+    re-layout of durations could give."""
+    tel = Telemetry()
+    _, result = _run_sim(_trace_jobs(), telemetry=tel)
+    events = tel.tracer.to_json()["traceEvents"]
+    sched = [e for e in events if e["pid"] == PID_SCHED and e["ph"] in "BE"]
+    assert [e["ts"] for e in sched] == sorted(e["ts"] for e in sched)
+    spans = _sched_spans(events)
+    cycles = [s for s in spans if s[0] == "qsch-cycle"]
+    assert cycles and all(s[3] == 0 for s in cycles)
+    assert all(set(s[4]) == {"t_sim", "scheduled", "preempted",
+                             "requeues"} for s in cycles)
+    for name, t0, t1, depth, _ in spans:
+        if name != "qsch-cycle":
+            assert depth >= 1
+            assert any(c[1] <= t0 <= t1 <= c[2] for c in cycles), name
+    uids = {j.uid for j in result.jobs}
+    schedules = [s for s in spans if s[0] == "rsch-schedule"]
+    assert schedules and all(s[4]["uid"] in uids for s in schedules)
+    for name in ("filter", "group-choice", "score", "slot-walk"):
+        inner = [s for s in spans if s[0] == name]
+        assert inner, name
+        for _, t0, t1, _, _ in inner:
+            assert any(r[1] <= t0 <= t1 <= r[2] for r in schedules), name
+    # Telemetry's cycle is the qsch-cycle phase, not a second timer.
+    assert math.isclose(tel.phase_totals["qsch-cycle"],
+                        sum(c[2] - c[1] for c in cycles) / 1e6,
+                        rel_tol=1e-6)
+
+
+def test_profiler_trace_holds_the_program_phases(topo, state, tmp_path):
+    """Under ``jax.profiler.trace`` the attached Telemetry's phases are
+    host events of the ``.xplane.pb``, on the profiler's clock; the
+    byte counters reach the registry and the Chrome trace."""
+    import glob
+    import jax
+    qsch = make_qsch(topo, state, policy=QueuePolicy.STRICT_FIFO,
+                     rsch_config=RSCHConfig(score_backend="interpret"))
+    tel = Telemetry()
+    tel.attach_qsch(qsch)
+    qsch.submit(_gang(uid=5, pods=3))
+    with jax.profiler.trace(str(tmp_path)):
+        result = qsch.cycle(state, 0.0)
+    assert len(result.scheduled) == 1
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    data = jax.profiler.ProfileData.from_file(path)
+    host = {e.name for p in data.planes if p.name.startswith("/host:")
+            for line in p.lines for e in line.events}
+    assert {"qsch-cycle", "snapshot", "rsch-schedule", "group-choice",
+            "slot-walk", "score-upload", "score-launch", "score-wait",
+            "score-fetch", "bind"} <= host
+    h2d = tel.registry.counter("kant_score_h2d_bytes_total").value()
+    d2h = tel.registry.counter("kant_score_d2h_bytes_total").value()
+    assert h2d > 0 and d2h == 2 * 4 * topo.n_nodes
+    counters = [e for e in tel.tracer.to_json()["traceEvents"]
+                if e["ph"] == "C"]
+    assert {e["name"] for e in counters} == {"score-h2d-bytes",
+                                             "score-d2h-bytes"}
+    assert counters[-1]["args"] == {"score-d2h-bytes": d2h}
 
 
 def test_pillar_toggles_disable_cleanly():

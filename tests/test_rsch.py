@@ -57,6 +57,27 @@ def test_gang_all_or_nothing(topo, state):
     assert state.total_allocated() == 0
 
 
+@pytest.mark.parametrize("backend", ["np", "interpret"])
+def test_gang_placement_enters_rsch_phases(topo, state, backend):
+    """A gang placement runs inside ``rsch-schedule``, which holds the
+    level-1 ``group-choice`` and the ``slot-walk``, and carries the
+    job's uid; the device backend's score call sits inside it too."""
+    from conftest import PhaseLog
+    rsch = _rsch(topo, score_backend=backend)
+    rsch.obs = log = PhaseLog()
+    res = rsch.schedule(_train_job(uid=7, n_pods=3, gpus=8), _snap(state))
+    assert res.placement is not None
+    outer = log.interval("rsch-schedule")
+    inner = ["group-choice", "slot-walk"]
+    if backend == "interpret":
+        inner.append("score-fetch")
+    for name in inner:
+        lo, hi = log.interval(name)
+        assert outer[0] < lo < hi < outer[1], name
+    assert log.interval("group-choice")[1] < log.interval("slot-walk")[0]
+    assert log.uids == {"rsch-schedule": 7}
+
+
 def test_feasible_checks_pool(topo, state):
     rsch = _rsch(topo)
     snap = _snap(state)
