@@ -141,21 +141,21 @@ def fail(msg: str) -> None:
 # Scheduler phases
 # ---------------------------------------------------------------------------
 def kernel_lowering(backend: str = "pallas") -> int:
-    """Lower the score+slots kernel on the tiles RSCH's call hands it;
-    return the number of Mosaic custom calls in the lowered program."""
-    import jax.numpy as jnp
+    """Lower the score call's one program on the tables RSCH's call
+    stages; return the number of Mosaic custom calls in it."""
     from repro.core.scoring import E_BINPACK
-    from repro.kernels import node_score, ops
+    from repro.kernels import ops
 
     n = SCHED_NODES
-    cols = (jnp.zeros(n, jnp.int32), jnp.zeros(n, jnp.int32),
-            jnp.ones(n, jnp.int32), jnp.zeros(n, jnp.float32),
-            jnp.zeros(n, jnp.float32))
-    tiles, _ = ops.to_tiles(cols, n)
+    cols = (np.zeros(n, np.int32), np.zeros(n, np.int32),
+            np.ones(n, np.int32), np.zeros(n, np.float32),
+            np.zeros(n, np.float32))
+    tables = ops.stage_tables(cols, n)
     w = E_BINPACK
-    lowered = node_score.node_scores_slots_pallas.lower(
-        *tiles, request=8, gpus_per_node=8, w_used=w.used, w_fit=w.fit,
-        w_group=w.group, w_topo=w.topo, interpret=(backend != "pallas"))
+    lowered = ops.scores_slots_program.lower(
+        *tables, n=n, request=8, gpus_per_node=8, w_used=w.used,
+        w_fit=w.fit, w_group=w.group, w_topo=w.topo,
+        interpret=(backend != "pallas"))
     return lowered.as_text().count("tpu_custom_call")
 
 
@@ -180,7 +180,7 @@ def trace_identity(n_nodes: int, n_jobs: int, seed: int,
     """Run one trace with ``backend`` and with ``"np"``; fail unless
     placements and metric series are identical."""
     from benchmarks.sched_scale_bench import _placement_key
-    from repro.kernels.node_score import node_scores_slots_pallas as kernel
+    from repro.kernels.ops import scores_slots_program as kernel
 
     variants0 = counter.of(kernel)
     dev = simulate(backend, n_nodes, n_jobs, seed)

@@ -13,16 +13,20 @@ single VPU pass over the node table:
   writes the score block back;
 * invalid nodes get ``-inf`` so downstream ``argmax`` needs no extra mask.
 
-The node axis is padded to the block size by ``ops.py``; padding rows have
-``mask = 0`` so they score ``-inf`` and can never win the argmax.
+The node axis is padded to the block size on the host by ``ops.py``
+(``stage_tables``), which hands the columns over as two tables that the
+kernels read in place (``node_scores_tables``,
+``node_scores_slots_tables``) and runs the kernel in one compiled
+program with the slice of its padding; padding rows have ``mask = 0``
+so they score ``-inf`` and can never win the argmax.
 
 Scalar parameters (request size, strategy weights) are closed over as
 Python floats, so the kernel body stays branch-free and every distinct
 (pod size, weight set) pair compiles its own variant: a 500-job
 ``training_trace`` on a 10,000-node cluster compiled the score+slots
 kernel 4 times on a TPU v5e (pod sizes 1, 2, 4 and 8 GPUs under the one
-E-Binpack weight set; 15 compiles in all with the padding ops).  A
-weight write from the tuning layer compiles another variant.
+E-Binpack weight set).  A weight write from the tuning layer compiles
+another variant.
 """
 
 from __future__ import annotations
@@ -85,77 +89,106 @@ def _score_slots_kernel(free_ref, used_ref, mask_ref, gload_ref, topo_ref,
                                ).astype(jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "request", "gpus_per_node", "w_used", "w_fit", "w_group", "w_topo",
-    "interpret"))
+# The kernels' static arguments: each value compiles its own variant.
+STATIC_ARGNAMES = ("request", "gpus_per_node", "w_used", "w_fit",
+                   "w_group", "w_topo", "interpret")
+
+
+def _grid(rows: int) -> tuple:
+    if rows % BLOCK_ROWS:
+        raise ValueError(f"rows ({rows}) must be a multiple of "
+                         f"{BLOCK_ROWS}")
+    return (rows // BLOCK_ROWS,)
+
+
+def _column() -> pl.BlockSpec:
+    """One block of a ``(rows, LANE)`` column per grid step."""
+    return pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0))
+
+
+def _row(k: int) -> pl.BlockSpec:
+    """One block of row ``k`` of a ``(k_rows, rows, LANE)`` table per
+    grid step: the kernel reads the column straight out of the table."""
+    return pl.BlockSpec((pl.squeezed, BLOCK_ROWS, LANE),
+                        lambda i: (k, i, 0))
+
+
+def _score_call(body, rows: int, in_specs, out_dtypes, name: str, *,
+                request: int, gpus_per_node: int, w_used: float,
+                w_fit: float, w_group: float, w_topo: float,
+                interpret: bool = False):
+    """``pallas_call`` of ``body`` over ``rows`` rows with the request and
+    weights closed over; the custom call is named ``name``, the name a
+    profiler trace shows for the kernel."""
+    kw = dict(request=float(request), inv_g=1.0 / float(gpus_per_node),
+              w_used=float(w_used), w_fit=float(w_fit),
+              w_group=float(w_group), w_topo=float(w_topo))
+    if body is _score_slots_kernel:
+        kw["request_i"] = int(request)
+    return pl.pallas_call(
+        functools.partial(body, **kw),
+        grid=_grid(rows),
+        in_specs=in_specs,
+        out_specs=[_column() for _ in out_dtypes],
+        out_shape=[jax.ShapeDtypeStruct((rows, LANE), dt)
+                   for dt in out_dtypes],
+        interpret=interpret,
+        name=name,
+    )
+
+
+def _columns(free, used, mask, group_load, topo_pref) -> tuple:
+    rows, lane = free.shape
+    if lane != LANE:
+        raise ValueError(f"lane dim must be {LANE}, got {lane}")
+    return rows, (free.astype(jnp.int32), used.astype(jnp.int32),
+                  mask.astype(jnp.int32), group_load.astype(jnp.float32),
+                  topo_pref.astype(jnp.float32))
+
+
+@functools.partial(jax.jit, static_argnames=STATIC_ARGNAMES)
 def node_scores_pallas(free: jnp.ndarray, used: jnp.ndarray,
                        mask: jnp.ndarray, group_load: jnp.ndarray,
-                       topo_pref: jnp.ndarray, *, request: int,
-                       gpus_per_node: int, w_used: float, w_fit: float,
-                       w_group: float, w_topo: float,
-                       interpret: bool = False) -> jnp.ndarray:
-    """Score a 2-D node table of shape (rows, LANE).
-
-    ``rows`` must be a multiple of ``BLOCK_ROWS``; callers go through
-    :func:`repro.kernels.ops.node_scores` which pads and reshapes.
-    """
-    rows, lane = free.shape
-    if lane != LANE:
-        raise ValueError(f"lane dim must be {LANE}, got {lane}")
-    if rows % BLOCK_ROWS:
-        raise ValueError(f"rows ({rows}) must be a multiple of "
-                         f"{BLOCK_ROWS}")
-    grid = (rows // BLOCK_ROWS,)
-    blk = lambda: pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0))
-    kernel = functools.partial(
-        _score_kernel, request=float(request),
-        inv_g=1.0 / float(gpus_per_node), w_used=float(w_used),
-        w_fit=float(w_fit), w_group=float(w_group), w_topo=float(w_topo))
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[blk(), blk(), blk(), blk(), blk()],
-        out_specs=blk(),
-        out_shape=jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
-        interpret=interpret,
-    )(free.astype(jnp.int32), used.astype(jnp.int32),
-      mask.astype(jnp.int32), group_load.astype(jnp.float32),
-      topo_pref.astype(jnp.float32))
+                       topo_pref: jnp.ndarray, **kw) -> jnp.ndarray:
+    """Score a 2-D node table of five (rows, LANE) columns; ``rows`` a
+    multiple of ``BLOCK_ROWS``."""
+    rows, cols = _columns(free, used, mask, group_load, topo_pref)
+    return _score_call(_score_kernel, rows, [_column()] * 5,
+                       (jnp.float32,), "node_scores_pallas", **kw)(*cols)[0]
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "request", "gpus_per_node", "w_used", "w_fit", "w_group", "w_topo",
-    "interpret"))
+@functools.partial(jax.jit, static_argnames=STATIC_ARGNAMES)
 def node_scores_slots_pallas(free: jnp.ndarray, used: jnp.ndarray,
                              mask: jnp.ndarray, group_load: jnp.ndarray,
-                             topo_pref: jnp.ndarray, *, request: int,
-                             gpus_per_node: int, w_used: float,
-                             w_fit: float, w_group: float, w_topo: float,
-                             interpret: bool = False):
-    """Fused (scores, pod_slots) over a 2-D node table of shape
-    (rows, LANE) — the batched gang-placement front half.  Layout
-    contract matches :func:`node_scores_pallas`."""
-    rows, lane = free.shape
-    if lane != LANE:
-        raise ValueError(f"lane dim must be {LANE}, got {lane}")
-    if rows % BLOCK_ROWS:
-        raise ValueError(f"rows ({rows}) must be a multiple of "
-                         f"{BLOCK_ROWS}")
-    grid = (rows // BLOCK_ROWS,)
-    blk = lambda: pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0))
-    kernel = functools.partial(
-        _score_slots_kernel, request=float(request),
-        request_i=int(request), inv_g=1.0 / float(gpus_per_node),
-        w_used=float(w_used), w_fit=float(w_fit), w_group=float(w_group),
-        w_topo=float(w_topo))
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[blk(), blk(), blk(), blk(), blk()],
-        out_specs=[blk(), blk()],
-        out_shape=[jax.ShapeDtypeStruct((rows, LANE), jnp.float32),
-                   jax.ShapeDtypeStruct((rows, LANE), jnp.int32)],
-        interpret=interpret,
-    )(free.astype(jnp.int32), used.astype(jnp.int32),
-      mask.astype(jnp.int32), group_load.astype(jnp.float32),
-      topo_pref.astype(jnp.float32))
+                             topo_pref: jnp.ndarray, **kw):
+    """Fused (scores, pod_slots) over five (rows, LANE) columns — the
+    batched gang-placement front half.  Layout contract matches
+    :func:`node_scores_pallas`."""
+    rows, cols = _columns(free, used, mask, group_load, topo_pref)
+    return _score_call(_score_slots_kernel, rows, [_column()] * 5,
+                       (jnp.float32, jnp.int32), "node_scores_slots_pallas",
+                       **kw)(*cols)
+
+
+# The node table as ``repro.kernels.ops`` stages it: free, used and mask
+# are the rows of one int32 ``(3, rows, LANE)`` table, group_load and
+# topo_pref of one float32 ``(2, rows, LANE)`` table.  The kernels read
+# their blocks straight out of the two, so nothing copies the table on
+# the device before they run.
+_TABLE_SPECS = [_row(0), _row(1), _row(2), _row(0), _row(1)]
+
+
+def node_scores_tables(ints: jnp.ndarray, floats: jnp.ndarray, **kw
+                       ) -> jnp.ndarray:
+    """:func:`node_scores_pallas` over the two staged tables."""
+    return _score_call(_score_kernel, ints.shape[1], _TABLE_SPECS,
+                       (jnp.float32,), "node_scores_pallas", **kw)(
+        ints, ints, ints, floats, floats)[0]
+
+
+def node_scores_slots_tables(ints: jnp.ndarray, floats: jnp.ndarray,
+                             **kw):
+    """:func:`node_scores_slots_pallas` over the two staged tables."""
+    return _score_call(_score_slots_kernel, ints.shape[1], _TABLE_SPECS,
+                       (jnp.float32, jnp.int32), "node_scores_slots_pallas",
+                       **kw)(ints, ints, ints, floats, floats)

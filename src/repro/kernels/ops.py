@@ -1,9 +1,12 @@
 """Public entry point for the node-scoring kernel.
 
-``node_scores`` accepts the natural 1-D node-table layout, pads/reshapes
-to the kernel's (rows, 128) tiling, dispatches to either the Pallas TPU
-kernel or the pure-jnp oracle, and slices the padding back off.  Padding
-rows carry ``mask = 0`` so they can never win the downstream argmax.
+``node_scores`` accepts the natural 1-D node-table layout and dispatches
+to either the Pallas TPU kernel or the pure-jnp oracle.  For the kernel
+the columns are staged on the host as two tables in its (rows, 128)
+tiling, padded to whole blocks, handed to the device in one transfer,
+and scored by one compiled program that also slices the padding back
+off.  Padding rows carry ``mask = 0`` so they can never win the
+downstream argmax.
 
 Backend selection:
 
@@ -19,6 +22,7 @@ phases (``score-upload``, ``score-launch``, ``score-wait``,
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Sequence
 
 import jax
@@ -35,23 +39,36 @@ _ROW = _ns.LANE * _ns.BLOCK_ROWS
 _FILLS = (0, 0, 0, 0.0, 0.0)
 
 
-def _pad_to(x: jnp.ndarray, n: int, fill=0) -> jnp.ndarray:
-    pad = n - x.shape[0]
-    if pad == 0:
-        return x
-    return jnp.concatenate(
-        [x, jnp.full((pad,), fill, dtype=x.dtype)], axis=0)
-
-
-def to_tiles(cols: Sequence, n: int) -> tuple:
-    """The five node columns as device arrays in the kernel's
-    ``(rows, LANE)`` tiling, padded to whole blocks.  Returns
-    ``(tiles, padded)``, ``padded`` being the padded node count."""
+def stage_tables(cols: Sequence, n: int) -> tuple:
+    """The five node columns staged on the host as the kernel reads
+    them: free, used and mask as the rows of one int32 table, group_load
+    and topo_pref of one float32 table, each ``(k, rows, LANE)`` in the
+    kernel's tiling and padded to whole blocks with ``_FILLS``.  Each
+    column is cast to the dtype the kernel casts it to.  Returns
+    ``(ints, floats)``."""
     padded = max(_ROW, -(-n // _ROW) * _ROW)
-    rows = padded // _ns.LANE
-    tiles = [_pad_to(jnp.asarray(a), padded, fill).reshape(rows, _ns.LANE)
-             for a, fill in zip(cols, _FILLS)]
-    return tiles, padded
+    ints = np.empty((3, padded), np.int32)
+    floats = np.empty((2, padded), np.float32)
+    for row, col, fill in zip((*ints, *floats), cols, _FILLS):
+        row[:n] = col
+        row[n:] = fill
+    return ints.reshape(3, -1, _ns.LANE), floats.reshape(2, -1, _ns.LANE)
+
+
+@functools.partial(jax.jit, static_argnames=("n",) + _ns.STATIC_ARGNAMES)
+def scores_program(ints, floats, *, n: int, **kw) -> jnp.ndarray:
+    """The score kernel over the staged tables and the slice of its
+    padding, as one compiled program: (n,) f32 scores."""
+    return _ns.node_scores_tables(ints, floats, **kw).reshape(-1)[:n]
+
+
+@functools.partial(jax.jit, static_argnames=("n",) + _ns.STATIC_ARGNAMES)
+def scores_slots_program(ints, floats, *, n: int, **kw):
+    """The score+slots kernel over the staged tables and the slice of
+    both outputs' padding, as one compiled program: (n,) f32 scores and
+    (n,) int32 pod slots."""
+    scores, slots = _ns.node_scores_slots_tables(ints, floats, **kw)
+    return scores.reshape(-1)[:n], slots.reshape(-1)[:n]
 
 
 def _kernel_kw(weights, request, gpus_per_node, w_used, w_fit, w_group,
@@ -73,20 +90,17 @@ def node_scores(free, used, mask, group_load, topo_pref, *, request: int,
     with ``-inf`` at invalid nodes."""
     kw = _kernel_kw(weights, request, gpus_per_node, w_used, w_fit,
                     w_group, w_topo)
-    free = jnp.asarray(free)
-    n = free.shape[0]
-
     if backend == "ref":
-        return node_scores_ref(free, jnp.asarray(used), jnp.asarray(mask),
-                               jnp.asarray(group_load),
+        return node_scores_ref(jnp.asarray(free), jnp.asarray(used),
+                               jnp.asarray(mask), jnp.asarray(group_load),
                                jnp.asarray(topo_pref), **kw)
     if backend not in ("pallas", "interpret"):
         raise ValueError(f"unknown backend {backend!r}")
 
-    tiles, padded = to_tiles((free, used, mask, group_load, topo_pref), n)
-    out = _ns.node_scores_pallas(
-        *tiles, interpret=(backend == "interpret"), **kw)
-    return out.reshape(padded)[:n]
+    n = np.shape(free)[0]
+    tables = stage_tables((free, used, mask, group_load, topo_pref), n)
+    return scores_program(*jax.device_put(tables), n=n,
+                          interpret=(backend == "interpret"), **kw)
 
 
 def node_scores_and_slots(free, used, mask, group_load, topo_pref, *,
@@ -103,12 +117,14 @@ def node_scores_and_slots(free, used, mask, group_load, topo_pref, *,
     :func:`repro.core.scoring.select_gang_slots`.
 
     The ``pallas`` and ``interpret`` backends return host numpy arrays:
-    the call uploads and pads the columns (``score-upload``), launches
-    the kernel and the reshape/slice of its outputs (``score-launch``),
-    and copies both outputs to the host (``score-fetch``), each a phase
-    of ``obs`` when one is attached.  With an observer it first waits
-    for the device apart (``score-wait``); without one the copies wait.
-    The ``ref`` backend returns device arrays, untimed.
+    the call stages the padded columns on the host as two tables and
+    hands both to the device in one transfer (``score-upload``), makes
+    one call of one compiled program, the kernel and the slice of its
+    padding (``score-launch``), and copies both outputs to the host
+    (``score-fetch``), each a phase of ``obs`` when one is attached.
+    With an observer it first waits for the device apart
+    (``score-wait``); without one the copies wait.  The ``ref`` backend
+    returns device arrays, untimed.
     """
     kw = _kernel_kw(weights, request, gpus_per_node, w_used, w_fit,
                     w_group, w_topo)
@@ -122,23 +138,21 @@ def node_scores_and_slots(free, used, mask, group_load, topo_pref, *,
 
     n = np.shape(free)[0]
     with obs_phase(obs, "score-upload"):
-        tiles, padded = to_tiles(
-            (free, used, mask, group_load, topo_pref), n)
+        tables = stage_tables((free, used, mask, group_load, topo_pref), n)
+        staged = jax.device_put(tables)
     with obs_phase(obs, "score-launch"):
-        scores, slots = _ns.node_scores_slots_pallas(
-            *tiles, interpret=(backend == "interpret"), **kw)
-        scores = scores.reshape(padded)[:n]
-        slots = slots.reshape(padded)[:n]
+        scores, slots = scores_slots_program(
+            *staged, n=n, interpret=(backend == "interpret"), **kw)
     if obs is not None:
         # Only under an observer: the block is one more host-device round
-        # trip (~0.4 ms per call on a TPU v5e, PERF.md) that the copies
+        # trip (0.4-0.6 ms per call on a TPU v5e, PERF.md) that the copies
         # below otherwise fold into their own wait.
         with obs_phase(obs, "score-wait"):
             jax.block_until_ready((scores, slots))
     with obs_phase(obs, "score-fetch"):
         scores, slots = np.asarray(scores), np.asarray(slots)
     if obs is not None:
-        obs_count(obs, "score-h2d-bytes", sum(t.nbytes for t in tiles))
+        obs_count(obs, "score-h2d-bytes", sum(t.nbytes for t in tables))
         obs_count(obs, "score-d2h-bytes", scores.nbytes + slots.nbytes)
     return scores, slots
 

@@ -109,10 +109,90 @@ def test_scores_and_slots_phases_under_observer(counts):
     np.testing.assert_array_equal(slots,
                                   np.where(want > NEG_INF, free // 4, 0))
     padded = 8192                     # one block of 64 x 128 nodes
+    # The five columns travel as an int32 and a float32 table: 4 bytes
+    # each per padded node, the bool mask included.
     want_counts = {
-        "score-h2d-bytes": padded * sum(c.dtype.itemsize for c in cols),
+        "score-h2d-bytes": padded * 5 * 4,
         "score-d2h-bytes": n * (4 + 4)} if counts else {}
     assert log.counted == want_counts
+
+
+def _eager_chain(cols, n, kw):
+    """The score call as it was before host staging: per column a jnp
+    pad, concatenate and reshape to the kernel's tiling, the kernels,
+    and a reshape and slice of each output, all eager."""
+    from repro.kernels import node_score as ns
+    padded = -(-n // (ns.LANE * ns.BLOCK_ROWS)) * ns.LANE * ns.BLOCK_ROWS
+    tiles = []
+    for c, fill in zip(cols, (0, 0, 0, 0.0, 0.0)):
+        c = jnp.asarray(c)
+        c = jnp.concatenate([c, jnp.full((padded - n,), fill, c.dtype)])
+        tiles.append(c.reshape(-1, ns.LANE))
+    scores, slots = ns.node_scores_slots_pallas(*tiles, interpret=True,
+                                                **kw)
+    alone = ns.node_scores_pallas(*tiles, interpret=True, **kw)
+    return (np.asarray(scores.reshape(padded)[:n]),
+            np.asarray(slots.reshape(padded)[:n]),
+            np.asarray(alone.reshape(padded)[:n]))
+
+
+def _typed_table(rng, n, counts, mask, loads):
+    free, used, m, gl, tp = _table(rng, n)
+    return (free.astype(counts), used.astype(counts), m.astype(mask),
+            (gl + rng.random(n)).astype(loads),
+            (tp + rng.random(n)).astype(loads))
+
+
+_KW = dict(request=4, gpus_per_node=8, w_used=E_BINPACK.used,
+           w_fit=E_BINPACK.fit, w_group=E_BINPACK.group,
+           w_topo=E_BINPACK.topo)
+
+
+@pytest.mark.parametrize("dtypes", [("int64", "bool", "float64"),
+                                    ("int32", "int32", "float32"),
+                                    ("int32", "bool", "float32")],
+                         ids=["wide", "rsch", "table"])
+@pytest.mark.parametrize("n", [1, 130, 8192, 8193, 10000])
+def test_staged_call_equals_eager_chain(n, dtypes):
+    """Host staging and one program give the eager chain's scores and
+    slots bit for bit, for every column dtype the callers hand in."""
+    from repro.kernels.ops import node_scores_and_slots
+    cols = _typed_table(np.random.default_rng(n), n, *dtypes)
+    want_scores, want_slots, want_alone = _eager_chain(cols, n, _KW)
+    scores, slots = node_scores_and_slots(*cols, backend="interpret",
+                                          **_KW)
+    assert scores.dtype == np.float32 and slots.dtype == np.int32
+    np.testing.assert_array_equal(scores, want_scores)
+    np.testing.assert_array_equal(slots, want_slots)
+    alone = node_scores(*cols, backend="interpret", **_KW)
+    assert alone.shape == (n,)
+    np.testing.assert_array_equal(np.asarray(alone), want_alone)
+
+
+def test_successive_staged_calls_share_no_state():
+    """A second call's staging leaves the first call's staged tables and
+    results as they were, and each call scores its own columns."""
+    from repro.kernels.ops import node_scores_and_slots, stage_tables
+    n = 1000
+    a = _typed_table(np.random.default_rng(1), n, "int64", "bool",
+                     "float64")
+    b = _typed_table(np.random.default_rng(2), n, "int64", "bool",
+                     "float64")
+    tables_a = stage_tables(a, n)
+    kept = [t.copy() for t in tables_a]
+    tables_b = stage_tables(b, n)
+    # One block of 64 x 128 nodes.
+    assert [t.shape for t in tables_a] == [(3, 64, 128), (2, 64, 128)]
+    for ta, tb, k in zip(tables_a, tables_b, kept):
+        np.testing.assert_array_equal(ta, k)
+        assert not np.shares_memory(ta, tb)
+    got_a = node_scores_and_slots(*a, backend="interpret", **_KW)
+    got_b = node_scores_and_slots(*b, backend="interpret", **_KW)
+    for got, cols in ((got_a, a), (got_b, b)):
+        want = _eager_chain(cols, n, _KW)
+        np.testing.assert_array_equal(got[0], want[0])
+        np.testing.assert_array_equal(got[1], want[1])
+    assert not np.array_equal(got_a[0], got_b[0])
 
 
 def test_no_valid_node_returns_minus_one():

@@ -8,6 +8,7 @@ that passes here is not a run on the chip.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,7 +16,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.core.scoring import E_BINPACK
-from repro.kernels import node_score as ns
+from repro.kernels import node_score as ns, ops
 from repro.kernels.wkv6 import wkv6_pallas
 
 NODE_BLOCK = ns.LANE * ns.BLOCK_ROWS
@@ -72,6 +73,45 @@ def test_node_score_kernels_compile(one_chip, kernel, n_nodes):
     compiled = kernel.lower(*_node_table(n_nodes, one_chip),
                             **_weights()).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# Instructions that run no device op of their own.
+_NO_OP = ("parameter", "get-tuple-element", "bitcast", "tuple")
+
+
+@pytest.mark.parametrize("program,key", [
+    (ops.scores_program, "node_scores_pallas"),
+    (ops.scores_slots_program, "node_scores_slots_pallas")],
+    ids=["scores", "scores_slots"])
+@pytest.mark.parametrize("n_nodes", [10_000, 1_000_000])
+def test_score_call_is_one_program_with_one_named_kernel(
+        one_chip, program, key, n_nodes):
+    """The score call's program, on the tables it is staged into, holds
+    one Mosaic kernel named after its kernel function, reading both
+    tables where they were handed in, and no other instruction that
+    runs on the device names that kernel or copies a table before it:
+    a trace finds the kernel's time by that name, and the kernel's time
+    must hold its reads of the table."""
+    rows = -(-n_nodes // NODE_BLOCK) * NODE_BLOCK // ns.LANE
+    tables = [jax.ShapeDtypeStruct((k, rows, ns.LANE), dt, sharding=one_chip)
+              for k, dt in ((3, jnp.int32), (2, jnp.float32))]
+    text = program.lower(*tables, n=n_nodes,
+                         **_weights()).compile().as_text()
+    entry = text[text.index("ENTRY"):].splitlines()[1:]
+    ran = []
+    for line in entry:
+        if " = " not in line:
+            continue
+        name, rest = line.strip().removeprefix("ROOT ").split(" = ", 1)
+        op = re.search(r" ([a-z][\w-]*)\(", rest).group(1)
+        if op not in _NO_OP:
+            ran.append((name, op, rest.split(", metadata=", 1)[0]))
+    kernels = [r for r in ran if r[1] == "custom-call"]
+    assert len(kernels) == 1 and kernels[0][0].startswith(f"%{key}")
+    assert "tpu_custom_call" in kernels[0][2]
+    assert not [r for r in ran if r is not kernels[0]
+                and key in r[0] + r[2]]
+    assert {op for _, op, _ in ran} == {"custom-call", "slice"}
 
 
 def test_wkv6_compiles_at_rwkv6_3b_widths(one_chip):
