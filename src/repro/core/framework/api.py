@@ -539,10 +539,11 @@ def obs_phase(obs, name: str, uid: Optional[int] = None):
     """Timed-phase context for an attached telemetry observer.
 
     QSCH, RSCH and the score call wrap each stage where its work happens
-    (``qsch-cycle`` ⊃ snapshot, queue-sort, ``rsch-schedule`` ⊃ filter,
-    ``group-choice``, score ⊃ ``score-upload`` / ``-launch`` / ``-wait``
-    / ``-fetch`` and ``slot-walk``, reserve-permit, bind, preempt; the
-    table is in docs/observability.md) in
+    (``qsch-cycle`` ⊃ snapshot, queue-sort, Backfill's ``head-attempt``
+    and ``backfill-pass`` ⊃ ``rsch-schedule`` ⊃ filter, ``group-choice``,
+    score ⊃ ``score-upload`` / ``-launch`` / ``-wait`` / ``-fetch`` and
+    ``slot-walk``, reserve-permit, bind, preempt; the table is in
+    docs/observability.md) in
     ``with obs_phase(self.obs, "..."):``; with ``obs is None`` (no
     telemetry attached) this returns a shared null context and the
     stage runs untimed and unchanged.  ``uid`` names the job a span

@@ -23,7 +23,7 @@ from .api import (AdmitPlugin, CycleContext, FilterPlugin, PermitPlugin,
                   PlacementPass, PlanFn, PostBindPlugin, PreemptPlugin,
                   ProfileSet, QueuePolicyPlugin, QueueSortPlugin,
                   ReservePlugin, SchedulingProfile, ScorePlugin,
-                  single_pass_plan)
+                  obs_phase, single_pass_plan)
 from .registry import register
 
 
@@ -296,31 +296,33 @@ class BackfillPolicy(QueuePolicyPlugin):
     def run_cycle(self, queue: List[Job], ctx: CycleContext) -> None:
         sched = ctx.sched
         head = queue[0]
-        if sched.try_place(head, ctx):
-            sched.head_blocked_since.pop(head.uid, None)
-        else:
-            blocked_since = sched.head_blocked_since.setdefault(
-                head.uid, ctx.now)
-            if ctx.now - blocked_since >= self.head_timeout:
-                # Stamp the eviction source so preempt_job's audit
-                # record names this plugin and its beneficiary.
-                sched._preempt_source = (self.preempt.name, head.uid)
-                try:
-                    self.preempt.execute(head, ctx)
-                finally:
-                    sched._preempt_source = None
-                if sched.try_place(head, ctx):
-                    sched.head_blocked_since.pop(head.uid, None)
+        with obs_phase(sched.obs, "head-attempt"):
+            if sched.try_place(head, ctx):
+                sched.head_blocked_since.pop(head.uid, None)
+            else:
+                blocked_since = sched.head_blocked_since.setdefault(
+                    head.uid, ctx.now)
+                if ctx.now - blocked_since >= self.head_timeout:
+                    # Stamp the eviction source so preempt_job's audit
+                    # record names this plugin and its beneficiary.
+                    sched._preempt_source = (self.preempt.name, head.uid)
+                    try:
+                        self.preempt.execute(head, ctx)
+                    finally:
+                        sched._preempt_source = None
+                    if sched.try_place(head, ctx):
+                        sched.head_blocked_since.pop(head.uid, None)
+                    else:
+                        ctx.result.blocked_head = head
                 else:
                     ctx.result.blocked_head = head
-            else:
-                ctx.result.blocked_head = head
         # Backfill pass: later jobs may use idle resources now.
-        for job in queue[1:]:
-            if job.state is not JobState.PENDING:
-                continue
-            sched.try_place(job, ctx,
-                            backfilled=ctx.result.blocked_head is not None)
+        with obs_phase(sched.obs, "backfill-pass"):
+            for job in queue[1:]:
+                if job.state is not JobState.PENDING:
+                    continue
+                sched.try_place(
+                    job, ctx, backfilled=ctx.result.blocked_head is not None)
 
 
 # ----------------------------------------------------------------------

@@ -13,8 +13,10 @@ single VPU pass over the node table:
   writes the score block back;
 * invalid nodes get ``-inf`` so downstream ``argmax`` needs no extra mask.
 
-The node axis is padded to the block size on the host by ``ops.py``
-(``stage_tables``), which hands the columns over as two tables that the
+The node axis is padded on the host by ``ops.py`` (``stage_tables``):
+to whole blocks of ``BLOCK_ROWS`` rows, or, for a table smaller than one
+block, to whole ``(8, 128)`` tiles, scored as one block of its own
+height.  It hands the columns over as two tables that the
 kernels read in place (``node_scores_tables``,
 ``node_scores_slots_tables``) and runs the kernel in one compiled
 program with the slice of its padding; padding rows have ``mask = 0``
@@ -94,23 +96,26 @@ STATIC_ARGNAMES = ("request", "gpus_per_node", "w_used", "w_fit",
                    "w_group", "w_topo", "interpret")
 
 
-def _grid(rows: int) -> tuple:
+def _block_rows(rows: int) -> int:
+    """Rows of one grid step: ``BLOCK_ROWS``, or the whole table where it
+    is smaller than one block (a multiple of ``SUBLANE`` rows)."""
+    if 0 < rows < BLOCK_ROWS and rows % SUBLANE == 0:
+        return rows
     if rows % BLOCK_ROWS:
         raise ValueError(f"rows ({rows}) must be a multiple of "
-                         f"{BLOCK_ROWS}")
-    return (rows // BLOCK_ROWS,)
+                         f"{BLOCK_ROWS}, or of {SUBLANE} below it")
+    return BLOCK_ROWS
 
 
-def _column() -> pl.BlockSpec:
+def _column(block: int) -> pl.BlockSpec:
     """One block of a ``(rows, LANE)`` column per grid step."""
-    return pl.BlockSpec((BLOCK_ROWS, LANE), lambda i: (i, 0))
+    return pl.BlockSpec((block, LANE), lambda i: (i, 0))
 
 
-def _row(k: int) -> pl.BlockSpec:
+def _row(k: int, block: int) -> pl.BlockSpec:
     """One block of row ``k`` of a ``(k_rows, rows, LANE)`` table per
     grid step: the kernel reads the column straight out of the table."""
-    return pl.BlockSpec((pl.squeezed, BLOCK_ROWS, LANE),
-                        lambda i: (k, i, 0))
+    return pl.BlockSpec((pl.squeezed, block, LANE), lambda i: (k, i, 0))
 
 
 def _score_call(body, rows: int, in_specs, out_dtypes, name: str, *,
@@ -118,23 +123,29 @@ def _score_call(body, rows: int, in_specs, out_dtypes, name: str, *,
                 w_fit: float, w_group: float, w_topo: float,
                 interpret: bool = False):
     """``pallas_call`` of ``body`` over ``rows`` rows with the request and
-    weights closed over; the custom call is named ``name``, the name a
-    profiler trace shows for the kernel."""
+    weights closed over; ``in_specs(block)`` gives the inputs' block
+    specs.  The custom call is named ``name``, the name a profiler trace
+    shows for the kernel."""
     kw = dict(request=float(request), inv_g=1.0 / float(gpus_per_node),
               w_used=float(w_used), w_fit=float(w_fit),
               w_group=float(w_group), w_topo=float(w_topo))
     if body is _score_slots_kernel:
         kw["request_i"] = int(request)
+    block = _block_rows(rows)
     return pl.pallas_call(
         functools.partial(body, **kw),
-        grid=_grid(rows),
-        in_specs=in_specs,
-        out_specs=[_column() for _ in out_dtypes],
+        grid=(rows // block,),
+        in_specs=in_specs(block),
+        out_specs=[_column(block) for _ in out_dtypes],
         out_shape=[jax.ShapeDtypeStruct((rows, LANE), dt)
                    for dt in out_dtypes],
         interpret=interpret,
         name=name,
     )
+
+
+def _columns_specs(block: int) -> list:
+    return [_column(block)] * 5
 
 
 def _columns(free, used, mask, group_load, topo_pref) -> tuple:
@@ -151,9 +162,9 @@ def node_scores_pallas(free: jnp.ndarray, used: jnp.ndarray,
                        mask: jnp.ndarray, group_load: jnp.ndarray,
                        topo_pref: jnp.ndarray, **kw) -> jnp.ndarray:
     """Score a 2-D node table of five (rows, LANE) columns; ``rows`` a
-    multiple of ``BLOCK_ROWS``."""
+    multiple of ``BLOCK_ROWS``, or of ``SUBLANE`` below it."""
     rows, cols = _columns(free, used, mask, group_load, topo_pref)
-    return _score_call(_score_kernel, rows, [_column()] * 5,
+    return _score_call(_score_kernel, rows, _columns_specs,
                        (jnp.float32,), "node_scores_pallas", **kw)(*cols)[0]
 
 
@@ -165,7 +176,7 @@ def node_scores_slots_pallas(free: jnp.ndarray, used: jnp.ndarray,
     batched gang-placement front half.  Layout contract matches
     :func:`node_scores_pallas`."""
     rows, cols = _columns(free, used, mask, group_load, topo_pref)
-    return _score_call(_score_slots_kernel, rows, [_column()] * 5,
+    return _score_call(_score_slots_kernel, rows, _columns_specs,
                        (jnp.float32, jnp.int32), "node_scores_slots_pallas",
                        **kw)(*cols)
 
@@ -175,13 +186,15 @@ def node_scores_slots_pallas(free: jnp.ndarray, used: jnp.ndarray,
 # topo_pref of one float32 ``(2, rows, LANE)`` table.  The kernels read
 # their blocks straight out of the two, so nothing copies the table on
 # the device before they run.
-_TABLE_SPECS = [_row(0), _row(1), _row(2), _row(0), _row(1)]
+def _table_specs(block: int) -> list:
+    return [_row(0, block), _row(1, block), _row(2, block), _row(0, block),
+            _row(1, block)]
 
 
 def node_scores_tables(ints: jnp.ndarray, floats: jnp.ndarray, **kw
                        ) -> jnp.ndarray:
     """:func:`node_scores_pallas` over the two staged tables."""
-    return _score_call(_score_kernel, ints.shape[1], _TABLE_SPECS,
+    return _score_call(_score_kernel, ints.shape[1], _table_specs,
                        (jnp.float32,), "node_scores_pallas", **kw)(
         ints, ints, ints, floats, floats)[0]
 
@@ -189,6 +202,6 @@ def node_scores_tables(ints: jnp.ndarray, floats: jnp.ndarray, **kw
 def node_scores_slots_tables(ints: jnp.ndarray, floats: jnp.ndarray,
                              **kw):
     """:func:`node_scores_slots_pallas` over the two staged tables."""
-    return _score_call(_score_slots_kernel, ints.shape[1], _TABLE_SPECS,
+    return _score_call(_score_slots_kernel, ints.shape[1], _table_specs,
                        (jnp.float32, jnp.int32), "node_scores_slots_pallas",
                        **kw)(ints, ints, ints, floats, floats)

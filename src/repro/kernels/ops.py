@@ -3,7 +3,8 @@
 ``node_scores`` accepts the natural 1-D node-table layout and dispatches
 to either the Pallas TPU kernel or the pure-jnp oracle.  For the kernel
 the columns are staged on the host as two tables in its (rows, 128)
-tiling, padded to whole blocks, handed to the device in one transfer,
+tiling, padded to whole blocks (or, below one block, to whole (8, 128)
+tiles), handed to the device in one transfer,
 and scored by one compiled program that also slices the padding back
 off.  Padding rows carry ``mask = 0`` so they can never win the
 downstream argmax.
@@ -34,19 +35,29 @@ from ..core.scoring import ScoreWeights
 from . import node_score as _ns
 from .ref import node_scores_ref
 
-_ROW = _ns.LANE * _ns.BLOCK_ROWS
+_BLOCK = _ns.LANE * _ns.BLOCK_ROWS
+_TILE = _ns.LANE * _ns.SUBLANE
 # Padding of (free, used, mask, group_load, topo_pref): mask 0 rows.
 _FILLS = (0, 0, 0, 0.0, 0.0)
+
+
+def padded_nodes(n: int) -> int:
+    """Nodes of the staged tables: ``n`` padded to whole blocks of
+    ``BLOCK_ROWS`` rows, or, below one block, to whole ``(8, 128)``
+    tiles, which the kernel scores as one block of that height."""
+    if n < _BLOCK:
+        return max(_TILE, -(-n // _TILE) * _TILE)
+    return -(-n // _BLOCK) * _BLOCK
 
 
 def stage_tables(cols: Sequence, n: int) -> tuple:
     """The five node columns staged on the host as the kernel reads
     them: free, used and mask as the rows of one int32 table, group_load
     and topo_pref of one float32 table, each ``(k, rows, LANE)`` in the
-    kernel's tiling and padded to whole blocks with ``_FILLS``.  Each
-    column is cast to the dtype the kernel casts it to.  Returns
+    kernel's tiling and padded to ``padded_nodes(n)`` with ``_FILLS``.
+    Each column is cast to the dtype the kernel casts it to.  Returns
     ``(ints, floats)``."""
-    padded = max(_ROW, -(-n // _ROW) * _ROW)
+    padded = padded_nodes(n)
     ints = np.empty((3, padded), np.int32)
     floats = np.empty((2, padded), np.float32)
     for row, col, fill in zip((*ints, *floats), cols, _FILLS):

@@ -55,7 +55,7 @@ def test_pallas_interpret_matches_ref(n, strat):
 
 def test_padding_rows_never_win():
     """Padding must carry -inf so argmax cannot select a phantom node."""
-    n = 130                                  # forces padding to 8192
+    n = 130                                  # forces padding to 1024
     free = np.full(n, 8, np.int32)
     used = np.zeros(n, np.int32)
     mask = np.zeros(n, bool)
@@ -108,7 +108,7 @@ def test_scores_and_slots_phases_under_observer(counts):
     np.testing.assert_allclose(scores, want, rtol=1e-6)
     np.testing.assert_array_equal(slots,
                                   np.where(want > NEG_INF, free // 4, 0))
-    padded = 8192                     # one block of 64 x 128 nodes
+    padded = 1024           # below one block: one (8, 128) tile's height
     # The five columns travel as an int32 and a float32 table: 4 bytes
     # each per padded node, the bool mask included.
     want_counts = {
@@ -152,12 +152,25 @@ _KW = dict(request=4, gpus_per_node=8, w_used=E_BINPACK.used,
                                     ("int32", "int32", "float32"),
                                     ("int32", "bool", "float32")],
                          ids=["wide", "rsch", "table"])
-@pytest.mark.parametrize("n", [1, 130, 8192, 8193, 10000])
+@pytest.mark.parametrize("n", [1, 128, 130, 1000, 1024, 8191, 8192, 8193,
+                               10000])
 def test_staged_call_equals_eager_chain(n, dtypes):
     """Host staging and one program give the eager chain's scores and
-    slots bit for bit, for every column dtype the callers hand in."""
-    from repro.kernels.ops import node_scores_and_slots
+    slots bit for bit, for every column dtype the callers hand in, and
+    the jnp oracle's slots exactly and scores to float32 rounding.  A
+    table below one block is staged at its own height in whole (8, 128)
+    tiles; from one block up, in whole blocks."""
+    from repro.kernels import node_score as ns
+    from repro.kernels.ops import node_scores_and_slots, stage_tables
+    from repro.kernels.ref import node_scores_slots_ref
     cols = _typed_table(np.random.default_rng(n), n, *dtypes)
+    rows = stage_tables(cols, n)[0].shape[1]
+    block = ns.LANE * ns.BLOCK_ROWS
+    if n < block:
+        assert rows % ns.SUBLANE == 0 and rows <= ns.BLOCK_ROWS
+        assert rows == -(-n // (ns.LANE * ns.SUBLANE)) * ns.SUBLANE
+    else:
+        assert rows * ns.LANE == -(-n // block) * block
     want_scores, want_slots, want_alone = _eager_chain(cols, n, _KW)
     scores, slots = node_scores_and_slots(*cols, backend="interpret",
                                           **_KW)
@@ -167,6 +180,12 @@ def test_staged_call_equals_eager_chain(n, dtypes):
     alone = node_scores(*cols, backend="interpret", **_KW)
     assert alone.shape == (n,)
     np.testing.assert_array_equal(np.asarray(alone), want_alone)
+    # On the CPU, XLA fuses the oracle's multiply-adds differently from
+    # the interpreted kernel's, so scores may differ in the last bit.
+    ref_scores, ref_slots = node_scores_slots_ref(
+        *(jnp.asarray(c) for c in cols), **_KW)
+    np.testing.assert_array_equal(slots, np.asarray(ref_slots))
+    np.testing.assert_allclose(scores, np.asarray(ref_scores), rtol=1e-6)
 
 
 def test_successive_staged_calls_share_no_state():
@@ -181,8 +200,8 @@ def test_successive_staged_calls_share_no_state():
     tables_a = stage_tables(a, n)
     kept = [t.copy() for t in tables_a]
     tables_b = stage_tables(b, n)
-    # One block of 64 x 128 nodes.
-    assert [t.shape for t in tables_a] == [(3, 64, 128), (2, 64, 128)]
+    # Below one block: 8 rows of 128 nodes.
+    assert [t.shape for t in tables_a] == [(3, 8, 128), (2, 8, 128)]
     for ta, tb, k in zip(tables_a, tables_b, kept):
         np.testing.assert_array_equal(ta, k)
         assert not np.shares_memory(ta, tb)

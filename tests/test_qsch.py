@@ -2,9 +2,9 @@
 
 import pytest
 
-from repro.core import (Job, JobKind, JobState, QueuePolicy, QuotaMode,
-                        PRIO_HIGH, PRIO_LOW)
-from conftest import make_qsch
+from repro.core import (ClusterState, Job, JobKind, JobState, QueuePolicy,
+                        QuotaMode, PRIO_HIGH, PRIO_LOW)
+from conftest import PhaseLog, make_qsch
 
 
 def _job(uid, gpus=8, n_pods=1, prio=50, t=0.0, tenant="t0", dur=3600.0):
@@ -68,6 +68,69 @@ def test_backfill_schedules_small_and_preempts_on_timeout(topo, state):
     # preempted job was requeued (§3.2.4)
     j2 = next(j for j in qsch.pending_jobs() if j.uid == 2)
     assert j2.requeue_count == 1 and j2.state is JobState.PENDING
+
+
+class CycleLog(PhaseLog):
+    """A recording observer with QSCH's cycle and decision hooks."""
+
+    def cycle_begin(self, now):
+        pass
+
+    def cycle_end(self, result, ctx):
+        pass
+
+    def emit_bind(self, job, sched, ctx):
+        pass
+
+    def emit_reject(self, job, sched, ctx, reason):
+        pass
+
+    def emit_preempt(self, victim, ctx, source):
+        pass
+
+
+def test_backfill_cycle_enters_head_attempt_then_backfill_pass(topo):
+    """With an observer attached, a Backfill cycle enters
+    ``head-attempt`` (the head's try) and then ``backfill-pass`` (the
+    jobs behind it), both inside ``qsch-cycle``; RSCH runs inside the
+    one that places.  Placements are the same without the observer."""
+    placed = {}
+    for observed in (False, True):
+        state = ClusterState.create(topo)
+        qsch = make_qsch(topo, state, policy=QueuePolicy.BACKFILL)
+        log = CycleLog()
+        if observed:
+            qsch.obs = qsch.rsch.obs = log
+        for i in range(15):
+            qsch.submit(_job(100 + i, gpus=8))
+        qsch.cycle(state, 0.0)
+        qsch.submit(_job(1, n_pods=2, gpus=8, t=10.0))   # blocked head
+        qsch.submit(_job(2, gpus=8, t=11.0))             # backfilled
+        log.events.clear()
+        blocked = qsch.cycle(state, 20.0)
+        assert blocked.blocked_head.uid == 1
+        assert [j.uid for j in blocked.scheduled] == [2]
+        if observed:
+            cycle = log.interval("qsch-cycle")
+            head = log.interval("head-attempt")
+            rest = log.interval("backfill-pass")
+            rsch = log.interval("rsch-schedule")
+            assert cycle[0] < head[0] < head[1] < rest[0] < rest[1] < cycle[1]
+            assert rest[0] < rsch[0] < rsch[1] < rest[1]
+        for uid in (100, 101):
+            done = next(j for j in qsch.running.values() if j.uid == uid)
+            qsch.on_complete(done, state, 30.0)
+        log.events.clear()
+        freed = qsch.cycle(state, 40.0)
+        assert [j.uid for j in freed.scheduled] == [1]
+        if observed:
+            head = log.interval("head-attempt")
+            rsch = log.interval("rsch-schedule")
+            assert head[0] < rsch[0] < rsch[1] < head[1]
+        placed[observed] = [
+            (j.uid, [(p.node, tuple(p.gpu_indices)) for p in j.placement.pods])
+            for j in sorted(qsch.running.values(), key=lambda j: j.uid)]
+    assert placed[True] == placed[False]
 
 
 def test_priority_preemption(topo, state):
