@@ -64,17 +64,17 @@ def _score_kernel(free_ref, used_ref, mask_ref, gload_ref, topo_ref,
     out_ref[...] = jnp.where(valid, score, NEG_INF)
 
 
-def _score_slots_kernel(free_ref, used_ref, mask_ref, gload_ref, topo_ref,
-                        score_ref, slots_ref, *, request: float,
-                        request_i: int, inv_g: float, w_used: float,
-                        w_fit: float, w_group: float, w_topo: float
-                        ) -> None:
+def _score_slots(free_ref, used_ref, mask_ref, gload_ref, topo_ref, *,
+                 request: float, request_i: int, inv_g: float,
+                 w_used: float, w_fit: float, w_group: float,
+                 w_topo: float):
     """Fused score + capacity expansion for batched gang placement.
 
-    Alongside every node's score the kernel emits its pod-slot count
+    Alongside every node's score the pass yields its pod-slot count
     ``floor(free / request)`` (0 where invalid), so one VPU pass over the
     node table feeds the whole-gang top-k slot selection — the per-pod
-    rescoring loop disappears (§3.4).
+    rescoring loop disappears (§3.4).  Returns ``(scores, slots)`` of
+    one block.
     """
     free_i = free_ref[...]
     free = free_i.astype(jnp.float32)
@@ -86,9 +86,26 @@ def _score_slots_kernel(free_ref, used_ref, mask_ref, gload_ref, topo_ref,
     exact = (free == request).astype(jnp.float32)
     score = (w_used * used * inv_g + w_fit * exact
              + w_group * gload + w_topo * topo)
-    score_ref[...] = jnp.where(valid, score, NEG_INF)
-    slots_ref[...] = jnp.where(valid, free_i // request_i, 0
-                               ).astype(jnp.int32)
+    return (jnp.where(valid, score, NEG_INF),
+            jnp.where(valid, free_i // request_i, 0).astype(jnp.int32))
+
+
+def _score_slots_kernel(free_ref, used_ref, mask_ref, gload_ref, topo_ref,
+                        score_ref, slots_ref, **kw) -> None:
+    """Scores and pod slots of one block, as two outputs."""
+    score_ref[...], slots_ref[...] = _score_slots(
+        free_ref, used_ref, mask_ref, gload_ref, topo_ref, **kw)
+
+
+def _packed_score_slots_kernel(free_ref, used_ref, mask_ref, gload_ref,
+                               topo_ref, out_ref, **kw) -> None:
+    """Scores and pod slots of one block as the two rows of one int32
+    output: the scores' float32 bits, then the slots.  One output is
+    one buffer for the host to fetch."""
+    score, slots = _score_slots(free_ref, used_ref, mask_ref, gload_ref,
+                                topo_ref, **kw)
+    out_ref[0] = jax.lax.bitcast_convert_type(score, jnp.int32)
+    out_ref[1] = slots
 
 
 # The kernels' static arguments: each value compiles its own variant.
@@ -107,9 +124,11 @@ def _block_rows(rows: int) -> int:
     return BLOCK_ROWS
 
 
-def _column(block: int) -> pl.BlockSpec:
-    """One block of a ``(rows, LANE)`` column per grid step."""
-    return pl.BlockSpec((block, LANE), lambda i: (i, 0))
+def _column(block: int, lead: tuple = ()) -> pl.BlockSpec:
+    """One block of a ``(*lead, rows, LANE)`` array per grid step, whole
+    along its ``lead`` dims: a ``(rows, LANE)`` column by default."""
+    zeros = (0,) * len(lead)
+    return pl.BlockSpec((*lead, block, LANE), lambda i: (*zeros, i, 0))
 
 
 def _row(k: int, block: int) -> pl.BlockSpec:
@@ -118,27 +137,29 @@ def _row(k: int, block: int) -> pl.BlockSpec:
     return pl.BlockSpec((pl.squeezed, block, LANE), lambda i: (k, i, 0))
 
 
-def _score_call(body, rows: int, in_specs, out_dtypes, name: str, *,
+def _score_call(body, rows: int, in_specs, outs, name: str, *,
                 request: int, gpus_per_node: int, w_used: float,
                 w_fit: float, w_group: float, w_topo: float,
                 interpret: bool = False):
     """``pallas_call`` of ``body`` over ``rows`` rows with the request and
     weights closed over; ``in_specs(block)`` gives the inputs' block
-    specs.  The custom call is named ``name``, the name a profiler trace
-    shows for the kernel."""
+    specs, and ``outs`` each output's leading dims and dtype: ``()`` for
+    a ``(rows, LANE)`` column, ``(k,)`` for a ``(k, rows, LANE)`` table.
+    The custom call is named ``name``, the name a profiler trace shows
+    for the kernel."""
     kw = dict(request=float(request), inv_g=1.0 / float(gpus_per_node),
               w_used=float(w_used), w_fit=float(w_fit),
               w_group=float(w_group), w_topo=float(w_topo))
-    if body is _score_slots_kernel:
+    if body is not _score_kernel:
         kw["request_i"] = int(request)
     block = _block_rows(rows)
     return pl.pallas_call(
         functools.partial(body, **kw),
         grid=(rows // block,),
         in_specs=in_specs(block),
-        out_specs=[_column(block) for _ in out_dtypes],
-        out_shape=[jax.ShapeDtypeStruct((rows, LANE), dt)
-                   for dt in out_dtypes],
+        out_specs=[_column(block, lead) for lead, _ in outs],
+        out_shape=[jax.ShapeDtypeStruct((*lead, rows, LANE), dt)
+                   for lead, dt in outs],
         interpret=interpret,
         name=name,
     )
@@ -165,7 +186,8 @@ def node_scores_pallas(free: jnp.ndarray, used: jnp.ndarray,
     multiple of ``BLOCK_ROWS``, or of ``SUBLANE`` below it."""
     rows, cols = _columns(free, used, mask, group_load, topo_pref)
     return _score_call(_score_kernel, rows, _columns_specs,
-                       (jnp.float32,), "node_scores_pallas", **kw)(*cols)[0]
+                       [((), jnp.float32)], "node_scores_pallas",
+                       **kw)(*cols)[0]
 
 
 @functools.partial(jax.jit, static_argnames=STATIC_ARGNAMES)
@@ -177,8 +199,8 @@ def node_scores_slots_pallas(free: jnp.ndarray, used: jnp.ndarray,
     :func:`node_scores_pallas`."""
     rows, cols = _columns(free, used, mask, group_load, topo_pref)
     return _score_call(_score_slots_kernel, rows, _columns_specs,
-                       (jnp.float32, jnp.int32), "node_scores_slots_pallas",
-                       **kw)(*cols)
+                       [((), jnp.float32), ((), jnp.int32)],
+                       "node_scores_slots_pallas", **kw)(*cols)
 
 
 # The node table as ``repro.kernels.ops`` stages it: free, used and mask
@@ -195,13 +217,16 @@ def node_scores_tables(ints: jnp.ndarray, floats: jnp.ndarray, **kw
                        ) -> jnp.ndarray:
     """:func:`node_scores_pallas` over the two staged tables."""
     return _score_call(_score_kernel, ints.shape[1], _table_specs,
-                       (jnp.float32,), "node_scores_pallas", **kw)(
+                       [((), jnp.float32)], "node_scores_pallas", **kw)(
         ints, ints, ints, floats, floats)[0]
 
 
 def node_scores_slots_tables(ints: jnp.ndarray, floats: jnp.ndarray,
-                             **kw):
-    """:func:`node_scores_slots_pallas` over the two staged tables."""
-    return _score_call(_score_slots_kernel, ints.shape[1], _table_specs,
-                       (jnp.float32, jnp.int32), "node_scores_slots_pallas",
-                       **kw)(ints, ints, ints, floats, floats)
+                             **kw) -> jnp.ndarray:
+    """:func:`node_scores_slots_pallas` over the two staged tables, as
+    one ``(2, rows, LANE)`` int32 table: row 0 the scores' float32
+    bits, row 1 the pod slots."""
+    return _score_call(_packed_score_slots_kernel, ints.shape[1],
+                       _table_specs, [((2,), jnp.int32)],
+                       "node_scores_slots_pallas", **kw)(
+        ints, ints, ints, floats, floats)[0]

@@ -214,6 +214,36 @@ def test_successive_staged_calls_share_no_state():
     assert not np.array_equal(got_a[0], got_b[0])
 
 
+@pytest.mark.parametrize("n", [1, 128, 130, 8193])
+def test_score_call_fetches_one_buffer(n):
+    """The score call's program returns one int32 device array, the
+    scores' float32 bits over the pod slots, and the call returns its
+    two rows as float32 scores and int32 slots, bit for bit those of the
+    five-column kernel on the same columns: NEG_INF and 0 slots at
+    masked nodes and at nodes with less free than the request."""
+    from repro.kernels.ops import (node_scores_and_slots,
+                                   scores_slots_program, stage_tables)
+    cols = _table(np.random.default_rng(n), n)
+    free, _, mask, _, _ = cols
+    mask[0], free[-1] = False, _KW["request"] - 1
+    packed = scores_slots_program(*jax.device_put(stage_tables(cols, n)),
+                                  n=n, interpret=True, **_KW)
+    assert isinstance(packed, jax.Array)
+    assert packed.shape == (2, 1, n) and packed.dtype == jnp.int32
+    scores, slots = node_scores_and_slots(*cols, backend="interpret",
+                                          **_KW)
+    assert scores.dtype == np.float32 and slots.dtype == np.int32
+    assert scores.shape == slots.shape == (n,)
+    np.testing.assert_array_equal(np.asarray(packed).reshape(2, n),
+                                  np.stack([scores.view(np.int32), slots]))
+    want_scores, want_slots, _ = _eager_chain(cols, n, _KW)
+    np.testing.assert_array_equal(scores, want_scores)
+    np.testing.assert_array_equal(slots, want_slots)
+    invalid = ~mask | (free < _KW["request"])
+    assert invalid[0] and invalid[-1]
+    assert (scores[invalid] == NEG_INF).all() and not slots[invalid].any()
+
+
 def test_no_valid_node_returns_minus_one():
     free = np.zeros(64, np.int32)
     used = np.full(64, 8, np.int32)

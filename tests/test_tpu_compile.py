@@ -115,6 +115,13 @@ def test_score_call_is_one_program_with_one_named_kernel(
     assert len(kernels) == 1 and kernels[0][0].startswith(f"%{key}")
     assert "tpu_custom_call" in kernels[0][2]
     others = [r for r in ran if r is not kernels[0]]
+    if program is ops.scores_slots_program:
+        # One output, the scores' bits and the slots as the rows of one
+        # int32 table, for the host to fetch as one buffer, and after
+        # the kernel one plain slice of its padding at every size (none
+        # of these sizes fills the 128-node rows of its padded table).
+        assert kernels[0][2].startswith("s32[2,")
+        assert [op for _, op, _ in others] == ["slice"]
     if n_nodes >= ns.LANE * ns.BLOCK_ROWS:
         assert not [r for r in others if key in r[0] + r[2]]
         assert {op for _, op, _ in ran} == {"custom-call", "slice"}
