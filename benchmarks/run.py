@@ -45,7 +45,7 @@ MODULES = [
     ("Fig 15  GFR vs scale", "benchmarks.fig15_gfr_scale", None),
     ("§3.4.3  snapshot bench", "benchmarks.snapshot_bench", []),
     ("§3.4    sched scale bench", "benchmarks.sched_scale_bench",
-     ["--smoke", "--check-regression"]),
+     ["--smoke"]),
     ("framework plugin bench", "benchmarks.plugin_bench", []),
     ("dynamics bench", "benchmarks.dynamics_bench", ["--smoke"]),
     ("federation bench", "benchmarks.federation_bench", ["--smoke"]),
@@ -53,7 +53,6 @@ MODULES = [
     ("elastic training bench", "benchmarks.elastic_bench", ["--smoke"]),
     ("observability bench", "benchmarks.obs_bench", ["--smoke"]),
     ("self-tuning bench", "benchmarks.tuning_bench", ["--smoke"]),
-    ("kernel  node-score bench", "benchmarks.kernel_bench", None),
     ("§Roofline table", "benchmarks.roofline", None),
 ]
 

@@ -2,43 +2,27 @@
 
 The paper's central engineering claim is that Kant sustains scheduling
 efficiency "in clusters ranging from hundreds to tens of thousands of
-GPUs".  The hot loop is gang placement; this benchmark tracks three
-generations of it:
-
-* **sequential** — one full filter+score pass per pod (the seed);
-* **legacy batched** — ONE fused pass + lazy-greedy heap slot selection
-  (PR 1; ``RSCHConfig(subset_scoring=False, slot_engine="heap")``);
-* **SoA core** (this PR's defaults) — structure-of-arrays cluster
-  columns, O(groups) tracked-aggregate preselection, subset level-2
-  scoring over the selected NodeNetGroups only, and the vectorized
-  top-k slot-chain engine (``slot_engine="topk"``).
-
-All three provably pick identical nodes; every A/B below asserts it.
+GPUs".  The hot loop is gang placement: structure-of-arrays cluster
+columns, O(groups) tracked-aggregate preselection, subset level-2
+scoring over the selected NodeNetGroups only, and the vectorized top-k
+slot walk, all on the host's numpy score path.
 
 Measured and gated:
 
 * per-cycle scheduling latency at 1k / 10k / 100k / 1M nodes (64-pod
-  gang, realistically fragmented snapshot);
-* **>= 3x** SoA speedup over legacy batched at 100k nodes, and SoA
-  **no slower than** legacy at 10k (the "<= PR-1 numbers" gate);
-* legacy gates carried forward: batched >= 5x sequential at 10k,
-  plugin-profile parity within 5%;
-* end-to-end byte-identity: full simulator runs across the
-  policy x strategy matrix at 1k and 10k nodes, SoA defaults vs the
-  legacy engine — identical placements, start times and pod GPU sets;
+  gang, realistically fragmented snapshot), host CPU;
+* plugin-profile parity: the legacy strategy shim and explicit default
+  profiles pick identical nodes, and the profiles cost within 5% per
+  cycle up to 10k nodes (interleaved timing);
 * **pipelined trace replay**: a multi-day training trace through the
   simulator with ``pipelined_cycles`` off vs on — placements must be
   identical; reports replay throughput, speculation hit/conflict
   stats, and the critical-path per-cycle time (cycle cost minus the
-  speculative work that overlaps binding I/O in a real deployment);
-* ``--check-regression``: compares this run's per-cycle latencies to
-  the committed ``BENCH_sched_scale.json`` baseline and fails on a
-  >25% regression at any common size.
+  speculative work that overlaps binding I/O in a real deployment).
 
 Usage::
 
-    PYTHONPATH=src python benchmarks/sched_scale_bench.py \
-        [--smoke] [--check-regression]
+    PYTHONPATH=src python benchmarks/sched_scale_bench.py [--smoke]
 
 ``--smoke`` trims node counts and repeat counts for CI.
 """
@@ -46,7 +30,6 @@ Usage::
 from __future__ import annotations
 
 import argparse
-import json
 import os
 import sys
 import time
@@ -67,9 +50,6 @@ from benchmarks.common import bench_seed, write_bench_json
 
 GANG_PODS = 64
 GPUS_PER_POD = 8
-
-# PR-1 behavior: full-width level-2 scoring + heap slot selection.
-LEGACY = dict(subset_scoring=False, slot_engine="heap")
 
 
 def make_topology(n_nodes: int) -> ClusterTopology:
@@ -96,35 +76,13 @@ def make_state(n_nodes: int, seed: int = 0) -> ClusterState:
     return state
 
 
-def bench_one(state: ClusterState, repeats: int, *, profiles=None,
-              **cfg_kw) -> tuple[float, list[list[int]]]:
-    """Best-of-N per-cycle latency (s) and the node picks of each cycle.
-
-    Minimum over repeats is the standard noise-robust estimator for a
-    deterministic microbenchmark."""
-    rsch = RSCH(state.topology,
-                RSCHConfig(train_strategy=Strategy.E_BINPACK, **cfg_kw),
-                profiles=profiles)
-    snap = FullSnapshotter().take(state)
-    job = Job(uid=1, tenant="bench", gpu_type=0, n_pods=GANG_PODS,
-              gpus_per_pod=GPUS_PER_POD, kind=JobKind.TRAIN)
-    times, picks = [], []
-    rsch.schedule(job, snap)                      # warm caches
-    for _ in range(repeats):
-        t0 = time.perf_counter()
-        result = rsch.schedule(job, snap)
-        times.append(time.perf_counter() - t0)
-        assert result.placement is not None, "bench job must be placeable"
-        picks.append([(p.node, p.gpu_indices, p.nic)
-                      for p in result.placement.pods])
-    return float(np.min(times)), picks
-
-
 def bench_pair(state: ClusterState, repeats: int
                ) -> tuple[float, float, list]:
-    """Interleaved best-of-N timing: legacy-shim RSCH vs explicit
-    default profiles, alternating per iteration so load drift hits both
-    equally.  Returns (t_legacy, t_profiles, profile picks)."""
+    """Interleaved best-of-N per-cycle latency (s): legacy-shim RSCH vs
+    explicit default profiles, alternating per iteration so load drift
+    hits both equally.  Minimum over repeats is the standard
+    noise-robust estimator for a deterministic microbenchmark.  Returns
+    (t_legacy, t_profiles, the node picks of every cycle of both)."""
     snap = FullSnapshotter().take(state)
     job = Job(uid=1, tenant="bench", gpu_type=0, n_pods=GANG_PODS,
               gpus_per_pod=GPUS_PER_POD, kind=JobKind.TRAIN)
@@ -137,30 +95,20 @@ def bench_pair(state: ClusterState, repeats: int
     explicit.schedule(job, snap)
     t_leg, t_prof, picks = [], [], []
     for _ in range(repeats * 2):
-        t0 = time.perf_counter()
-        legacy.schedule(job, snap)
-        t_leg.append(time.perf_counter() - t0)
-        t0 = time.perf_counter()
-        result = explicit.schedule(job, snap)
-        t_prof.append(time.perf_counter() - t0)
-        picks.append([(p.node, p.gpu_indices, p.nic)
-                      for p in result.placement.pods])
+        for rsch, times in ((legacy, t_leg), (explicit, t_prof)):
+            t0 = time.perf_counter()
+            result = rsch.schedule(job, snap)
+            times.append(time.perf_counter() - t0)
+            assert result.placement is not None, (
+                "bench job must be placeable")
+            picks.append([(p.node, p.gpu_indices, p.nic)
+                          for p in result.placement.pods])
     return float(np.min(t_leg)), float(np.min(t_prof)), picks
 
 
 # ----------------------------------------------------------------------
-# End-to-end byte-identity: simulator runs across policy x strategy
+# Pipelined multi-day trace replay
 # ----------------------------------------------------------------------
-def _matrix_jobs(rng, n, max_pods):
-    return [Job(uid=i, tenant=f"t{i % 3}", gpu_type=0,
-                n_pods=int(rng.integers(1, max_pods + 1)),
-                gpus_per_pod=int(rng.choice([1, 2, 4, 8])),
-                duration=float(rng.integers(300, 6000)),
-                submit_time=float(rng.integers(0, 1800)),
-                priority=int(rng.integers(0, 3)),
-                kind=JobKind.TRAIN) for i in range(n)]
-
-
 def _placement_key(jobs):
     out = []
     for j in sorted(jobs, key=lambda j: j.uid):
@@ -173,45 +121,6 @@ def _placement_key(jobs):
     return out
 
 
-def _run_sim(n_nodes, policy, strategy, *, rsch_kw=None, n_jobs=48,
-             seed=0, pipelined=False):
-    topo = make_topology(n_nodes)
-    state = ClusterState.create(topo)
-    quota = QuotaManager({f"t{i}": {0: 10 ** 9} for i in range(3)})
-    rsch = RSCH(topo, RSCHConfig(train_strategy=strategy,
-                                 **(rsch_kw or {})))
-    qsch = QSCH(quota, rsch, QSCHConfig(policy=policy))
-    sim = Simulator(state, qsch,
-                    SimConfig(pipelined_cycles=pipelined))
-    rng = np.random.default_rng(seed)
-    max_pods = max(2, n_nodes // 16)
-    t0 = time.perf_counter()
-    res = sim.run(_matrix_jobs(rng, n_jobs, min(max_pods, 8)))
-    wall = time.perf_counter() - t0
-    return _placement_key(res.jobs), res, wall
-
-
-def identity_matrix(sizes, n_jobs, seed) -> int:
-    """SoA defaults vs the legacy engine across policy x strategy at
-    each size: full-run placements must be byte-identical."""
-    checked = 0
-    for n in sizes:
-        for policy in QueuePolicy:
-            for strategy in Strategy:
-                a, _, _ = _run_sim(n, policy, strategy, rsch_kw=LEGACY,
-                                   n_jobs=n_jobs, seed=seed)
-                b, _, _ = _run_sim(n, policy, strategy,
-                                   n_jobs=n_jobs, seed=seed)
-                assert a == b, (
-                    f"SoA engine diverged from legacy: {n} nodes, "
-                    f"{policy.value}, {strategy.value}")
-                checked += 1
-    return checked
-
-
-# ----------------------------------------------------------------------
-# Pipelined multi-day trace replay
-# ----------------------------------------------------------------------
 def trace_replay(n_nodes: int, n_jobs: int, seed: int) -> dict:
     """Replay a multi-day contended training trace with pipelining off
     vs on: placements must match; report throughput + pipeline stats."""
@@ -270,144 +179,40 @@ def trace_replay(n_nodes: int, n_jobs: int, seed: int) -> dict:
     }
 
 
-# ----------------------------------------------------------------------
-# Regression guard vs the committed baseline
-# ----------------------------------------------------------------------
-BASELINE_PATH = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "BENCH_sched_scale.json")
-REGRESSION_TOLERANCE = 1.25
-
-
-def check_regression(rows: dict, baseline_path: str = BASELINE_PATH
-                     ) -> list:
-    """Fail on a >25% per-cycle regression vs the committed baseline at
-    any size both runs measured.
-
-    The gated metric is the SoA-over-legacy speedup, not raw wall
-    time: both paths are timed in the SAME run, so the ratio cancels
-    machine speed and the guard works on any CI runner — while still
-    catching changes that slow the SoA core relative to the frozen
-    legacy engine.  Raw per-cycle ms is reported alongside for eyes.
-    """
-    if not os.path.exists(baseline_path):
-        print(f"    [regression] no baseline at {baseline_path}; "
-              f"skipping (commit one to arm the guard)")
-        return []
-    with open(baseline_path) as f:
-        base = json.load(f).get("per_cycle", {})
-    table = []
-    failures = []
-    for size, row in rows.items():
-        if size < 10_000:
-            # Below 10k both engines finish in well under a millisecond
-            # and the speedup ratio is timer jitter, not signal — the
-            # subset-scoring win only separates from noise at scale.
-            continue
-        ref = base.get(str(size)) or base.get(size)
-        if not ref or "soa_speedup" not in ref:
-            continue
-        rel = ref["soa_speedup"] / row["soa_speedup"]
-        table.append({"nodes": int(size),
-                      "baseline_ms": ref["soa_s"] * 1e3,
-                      "current_ms": row["soa_s"] * 1e3,
-                      "baseline_speedup": ref["soa_speedup"],
-                      "current_speedup": row["soa_speedup"],
-                      "relative_slowdown": rel})
-        flag = "REGRESSION" if rel > REGRESSION_TOLERANCE else "ok"
-        print(f"    [regression] {size:>8} nodes: speedup "
-              f"{ref['soa_speedup']:.2f}x -> {row['soa_speedup']:.2f}x "
-              f"(rel {rel:.2f}); per-cycle {ref['soa_s'] * 1e3:.2f}ms -> "
-              f"{row['soa_s'] * 1e3:.2f}ms  {flag}")
-        if rel > REGRESSION_TOLERANCE:
-            failures.append((size, rel))
-    assert not failures, (
-        f"SoA per-cycle regression >25% vs committed baseline "
-        f"(size, relative slowdown): {failures}")
-    return table
-
-
-def run_bench(smoke: bool = False, regression: bool = False) -> dict:
+def run_bench(smoke: bool = False) -> dict:
     seed = bench_seed()
     if smoke:
         sizes = (1000, 10_000)
-        matrix_sizes = (1000,)
-        repeats, matrix_jobs = 9, 32
+        repeats = 9
         replay_nodes, replay_jobs = 128, 300
     else:
         sizes = (1000, 10_000, 100_000, 1_000_000)
-        matrix_sizes = (1000, 10_000)
-        repeats, matrix_jobs = 15, 48
+        repeats = 15
         replay_nodes, replay_jobs = 256, 800
 
     rows = {}
-    print(f"{'nodes':>8s} {'sequential':>12s} {'legacy':>12s} "
-          f"{'SoA':>12s} {'SoA/legacy':>10s} {'pods/s (SoA)':>13s}")
+    print(f"{'nodes':>8s} {'per-cycle':>12s} {'profiles':>12s} "
+          f"{'overhead':>9s} {'pods/s':>9s}")
     for n in sizes:
         state = make_state(n, seed=seed)
-        t_leg, picks_leg = bench_one(state, repeats, **LEGACY)
-        t_soa, picks_soa = bench_one(state, repeats)
-        assert picks_leg == picks_soa, (
-            f"SoA placement diverged from legacy batched at {n} nodes")
-        row = {"legacy_s": t_leg, "soa_s": t_soa,
-               "soa_speedup": t_leg / t_soa,
-               "placements_per_s": GANG_PODS / t_soa}
+        t_shim, t_prof, picks = bench_pair(state, repeats)
+        assert all(p == picks[0] for p in picks), (
+            f"profile-built RSCH diverged from the legacy shim at {n} "
+            f"nodes")
+        row = {"per_cycle_s": t_shim, "profile_s": t_prof,
+               "profile_overhead": t_prof / t_shim - 1.0,
+               "placements_per_s": GANG_PODS / t_shim}
         if n <= 10_000:
-            # Seed-era sequential loop: 64 full passes per gang.  Too
-            # slow to time beyond 10k, where batched is the only game.
-            t_seq, picks_seq = bench_one(state, repeats,
-                                         batched_gang=False, **LEGACY)
-            assert picks_seq == picks_leg, (
-                f"batched placement diverged from sequential at {n} "
-                f"nodes")
-            row["sequential_s"] = t_seq
-            row["batched_speedup"] = t_seq / t_leg
-            # Plugin-framework parity (api_redesign acceptance gate):
-            # interleaved timing so load drift hits both paths equally.
-            t_bat2, t_prof, picks_prof = bench_pair(state, repeats)
-            assert all(p == picks_soa[0] for p in picks_prof), (
-                f"profile-built RSCH diverged at {n} nodes")
-            row["profile_s"] = t_prof
-            row["profile_overhead"] = t_prof / t_bat2 - 1.0
-            # 100us absolute floor: the SoA path is fast enough at 1k
-            # nodes that a relative-only bound measures timer jitter.
-            assert t_prof <= max(t_bat2 * 1.05, t_bat2 + 100e-6), (
-                f"profile engine must stay within 5% of the batched "
-                f"path at {n} nodes, got {row['profile_overhead']:+.1%}")
-        seq = row.get("sequential_s")
-        print(f"{n:8d} "
-              + (f"{seq * 1e3:10.2f}ms" if seq else f"{'—':>12s}")
-              + f" {t_leg * 1e3:10.2f}ms {t_soa * 1e3:10.2f}ms "
-              f"{row['soa_speedup']:9.1f}x "
-              f"{GANG_PODS / t_soa:11.0f}/s")
+            # Plugin-framework parity (api_redesign acceptance gate).
+            # 100us absolute floor: the path is fast enough at 1k nodes
+            # that a relative-only bound measures timer jitter.
+            assert t_prof <= max(t_shim * 1.05, t_shim + 100e-6), (
+                f"profile engine must stay within 5% of the shim path "
+                f"at {n} nodes, got {row['profile_overhead']:+.1%}")
+        print(f"{n:8d} {t_shim * 1e3:10.2f}ms {t_prof * 1e3:10.2f}ms "
+              f"{row['profile_overhead']:+8.1%} "
+              f"{row['placements_per_s']:9.0f}")
         rows[n] = row
-
-    bar = rows.get(10_000)
-    if bar is not None and "batched_speedup" in bar:
-        assert bar["batched_speedup"] >= 5.0, (
-            f"batched gang placement must be >=5x faster than "
-            f"sequential at 10k nodes, got {bar['batched_speedup']:.1f}x")
-        # "<= PR-1 numbers" gate: the SoA defaults may not cost more
-        # than the legacy batched path at 10k (5% timer-noise floor).
-        assert bar["soa_s"] <= bar["legacy_s"] * 1.05, (
-            f"SoA core slower than legacy batched at 10k nodes: "
-            f"{bar['soa_s'] * 1e3:.2f}ms vs {bar['legacy_s'] * 1e3:.2f}ms")
-        print(f"[ok] 10k: batched {bar['batched_speedup']:.1f}x >= 5x "
-              f"sequential; SoA {bar['soa_speedup']:.2f}x legacy")
-    big = rows.get(100_000)
-    if big is not None:
-        assert big["soa_speedup"] >= 3.0, (
-            f"SoA core must be >=3x faster than legacy batched at 100k "
-            f"nodes, got {big['soa_speedup']:.1f}x")
-        print(f"[ok] 100k: SoA {big['soa_speedup']:.1f}x >= 3x legacy")
-    giant = rows.get(1_000_000)
-    if giant is not None:
-        print(f"[ok] 1M-node per-cycle: {giant['soa_s'] * 1e3:.1f}ms "
-              f"({giant['placements_per_s']:.0f} pods/s)")
-
-    checked = identity_matrix(matrix_sizes, matrix_jobs, seed)
-    print(f"[ok] policy x strategy identity matrix: {checked} "
-          f"simulator A/Bs byte-identical "
-          f"(sizes {list(matrix_sizes)})")
 
     replay = trace_replay(replay_nodes, replay_jobs, seed)
     hit_pool = max(1, replay["hits"] + replay["misses"])
@@ -419,13 +224,9 @@ def run_bench(smoke: bool = False, regression: bool = False) -> dict:
           f"spec hit rate {replay['hits']}/{hit_pool}, "
           f"{replay['conflicts']} conflicts, {replay['errors']} errors")
 
-    payload = {"per_cycle": {str(k): v for k, v in rows.items()},
-               "identity_matrix_runs": checked,
-               "trace_replay": replay,
-               "smoke": smoke, "seed": seed}
-    if regression:
-        payload["regression"] = check_regression(rows)
-    write_bench_json("sched_scale", payload)
+    write_bench_json("sched_scale", {
+        "per_cycle": {str(k): v for k, v in rows.items()},
+        "trace_replay": replay, "smoke": smoke, "seed": seed})
     return rows
 
 
@@ -433,11 +234,8 @@ def main(argv=None) -> dict:
     parser = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     parser.add_argument("--smoke", action="store_true",
                         help="trimmed sizes/repeats for CI")
-    parser.add_argument("--check-regression", action="store_true",
-                        help="fail on >25% per-cycle regression vs the "
-                             "committed BENCH_sched_scale.json")
     args = parser.parse_args(argv)
-    return run_bench(smoke=args.smoke, regression=args.check_regression)
+    return run_bench(smoke=args.smoke)
 
 
 if __name__ == "__main__":

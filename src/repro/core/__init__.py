@@ -21,8 +21,7 @@ from .qsch import QSCH, QSCHConfig, QueuePolicy
 from .quota import QuotaManager, QuotaMode
 from .rsch import RSCH, RSCHConfig, Strategy, profiles_from_config
 from .scoring import (BINPACK, E_BINPACK, E_SPREAD, SPREAD, ScoreWeights,
-                      combine_weights, compute_node_scores, node_scores_np,
-                      select_gang_slots)
+                      combine_weights, node_scores_np, select_gang_slots)
 from .simulator import SimConfig, Simulator, SimResult
 from .snapshot import (FullSnapshotter, IncrementalSnapshotter, Snapshot,
                        snapshots_equal)
@@ -44,7 +43,7 @@ __all__ = [
     "QSCH", "QSCHConfig", "QueuePolicy", "QuotaManager",
     "QuotaMode", "RSCH", "RSCHConfig", "Strategy", "BINPACK", "E_BINPACK",
     "E_SPREAD", "SPREAD", "ScoreWeights", "combine_weights",
-    "compute_node_scores", "node_scores_np", "select_gang_slots",
+    "node_scores_np", "select_gang_slots",
     "SimConfig", "Simulator", "SimResult", "FullSnapshotter",
     "IncrementalSnapshotter", "Snapshot", "snapshots_equal",
     "ClusterTopology", "small_topology", "training_cluster_topology",

@@ -46,8 +46,7 @@ from .framework.builtin import (GpuTypeFilter, HealthFilter, binpack_pass,
                                 spread_pass)
 from .job import Job, JobKind, Placement, PodPlacement
 from .scoring import (NEG_INF, ScoreWeights, combine_weights,
-                      compute_node_scores, node_scores_np,
-                      select_gang_slots)
+                      node_scores_np, select_gang_slots)
 from .snapshot import Snapshot
 from .topology import ClusterTopology
 
@@ -78,31 +77,12 @@ class RSCHConfig:
     espread_small_pod_gpus: int = 8
     # Schedule EP-style jobs at HBD granularity (§3.3.5 Scale-Up).
     hbd_granular_ep: bool = True
-    # Batched gang placement (§3.4): one fused filter+score pass +
-    # capacity-aware top-k slot selection for the whole gang, instead of
-    # re-scoring every node once per pod.  The sequential path is kept
-    # for A/B benchmarking (benchmarks/sched_scale_bench.py).
-    batched_gang: bool = True
     # Score-pass backend: "np" (numpy, simulator default), "ref" (jnp
     # oracle), "interpret" (Pallas on CPU), "pallas" (compiled TPU).
     score_backend: str = "np"
     # Same-node co-location bonus per already-placed pod of the job
     # (node-level E-Binpack, §3.3.3).
     colocate_bonus: float = 2.0
-    # Subset scoring (million-node core): for default Filter chains,
-    # Level-1 preselection runs on snapshot-maintained per-group
-    # aggregates (O(groups), patched row-wise on placement deltas) and
-    # the Level-2 score pass touches only the selected groups' member
-    # nodes — exact-identical to the full-width pass.  Falls back to
-    # full width for custom Filter chains, non-"np" backends, and
-    # decision-audit capture.
-    subset_scoring: bool = True
-    # Gang slot-selection engine: "topk" (vectorized sort + chain
-    # emission), "heap" (the lazy-greedy loop, kept as the A/B oracle),
-    # or "topk_kernel" (jax.lax.top_k prefilter).  The vectorized
-    # engines auto-fall-back to the heap when plugin weights make slot
-    # chains decreasing (see scoring.chains_nondecreasing).
-    slot_engine: str = "topk"
 
 
 def profiles_from_config(config: RSCHConfig) -> ProfileSet:
@@ -403,16 +383,7 @@ class RSCH:
         if not pool.any():
             return fail("empty node pool")
 
-        # Subset scoring (million-node core): with a default Filter
-        # chain, the numpy backend and no audit capture, Level 1 runs on
-        # snapshot-maintained per-group aggregates and Level 2 touches
-        # only the selected groups' member nodes — exact-identical to
-        # the full-width pass (tests/test_scale.py), but per-attempt
-        # cost scales with the job's group footprint, not cluster size.
-        use_subset = (default_pool and self.config.subset_scoring
-                      and self.config.batched_gang
-                      and self.config.score_backend == "np"
-                      and capture is None)
+        use_subset = self._use_subset(default_pool, capture)
 
         # --- Level 1: NodeNetGroup preselection (§3.4.2) ---------------
         with obs_phase(obs, "group-choice"):
@@ -485,16 +456,10 @@ class RSCH:
                 topo_pref = group_pref[topo.leaf_id]
                 in_groups = topo_pref > 0.0
                 gload_nodes = group_load[topo.leaf_id]
-                if self.config.batched_gang:
-                    nodes = self._select_nodes_batched(
-                        job, snap, pool & in_groups, gload_nodes,
-                        topo_pref, weights, colocate,
-                        np.where(in_groups, pod_slots, 0), extra,
-                        score_out)
-                else:
-                    nodes = self._select_nodes_sequential(
-                        job, snap, pool, in_groups, gload_nodes,
-                        topo_pref, weights, colocate, extra)
+                nodes = self._select_nodes_full(
+                    job, snap, pool & in_groups, gload_nodes, topo_pref,
+                    weights, colocate, np.where(in_groups, pod_slots, 0),
+                    extra, score_out)
         if nodes is None:
             return fail("gang placement failed")
         if pa is not None:
@@ -647,22 +612,38 @@ class RSCH:
                 "extra": extra}
 
     # ------------------------------------------------------------------
-    # Node selection: batched (one fused pass) vs sequential (per pod)
+    # Node selection: one fused pass over every node, or over the
+    # selected groups' member nodes (subset scoring)
     # ------------------------------------------------------------------
-    def _select_nodes_batched(self, job: Job, snap: Snapshot,
-                              mask: np.ndarray, gload_nodes: np.ndarray,
-                              topo_pref: np.ndarray, weights: ScoreWeights,
-                              colocate: float,
-                              slots: Optional[np.ndarray] = None,
-                              extra: Optional[np.ndarray] = None,
-                              score_out: Optional[Dict] = None
-                              ) -> Optional[List[int]]:
+    def _use_subset(self, default_pool: bool,
+                    capture: Optional[Dict]) -> bool:
+        """Subset scoring (million-node core), chosen from what RSCH
+        observes: with a default Filter chain, the numpy backend and no
+        decision-audit capture, Level 1 runs on snapshot-maintained
+        per-group aggregates (O(groups), patched row-wise on placement
+        deltas) and Level 2 scores only the selected groups' member
+        nodes — exact-identical to the full-width pass
+        (tests/test_scale.py), but per-attempt cost scales with the
+        job's group footprint, not cluster size.  Anything else scores
+        the full width."""
+        return (default_pool and self.config.score_backend == "np"
+                and capture is None)
+
+    def _select_nodes_full(self, job: Job, snap: Snapshot,
+                           mask: np.ndarray, gload_nodes: np.ndarray,
+                           topo_pref: np.ndarray, weights: ScoreWeights,
+                           colocate: float,
+                           slots: Optional[np.ndarray] = None,
+                           extra: Optional[np.ndarray] = None,
+                           score_out: Optional[Dict] = None
+                           ) -> Optional[List[int]]:
         """Whole-gang placement from ONE filter+score pass (§3.4).
 
         The fused pass scores every node once; capacity expansion turns
         each node into ``floor(free/gpus_per_pod)`` pod slots and the
-        heap-based top-k selection emulates the sequential argmax loop
-        exactly (same nodes, same order, same tie-breaking).
+        top-k slot selection (:func:`select_gang_slots`) emulates the
+        per-pod argmax loop exactly (same nodes, same order, same
+        tie-breaking).
         """
         backend = self.config.score_backend
         if backend == "np":
@@ -689,7 +670,7 @@ class RSCH:
             return select_gang_slots(
                 scores, snap.free_gpus, job.gpus_per_pod, job.n_pods,
                 fit_weight=weights.fit, colocate_bonus=colocate,
-                slots=slots, engine=self.config.slot_engine)
+                slots=slots)
 
     def _select_nodes_subset(self, job: Job, snap: Snapshot,
                              pool: np.ndarray, selected_groups: List[int],
@@ -722,44 +703,10 @@ class RSCH:
             order = select_gang_slots(
                 scores, free_sub, job.gpus_per_pod, job.n_pods,
                 fit_weight=weights.fit, colocate_bonus=colocate,
-                slots=slots, engine=self.config.slot_engine)
+                slots=slots)
         if order is None:
             return None
         return [int(sub[p]) for p in order]
-
-    def _select_nodes_sequential(self, job: Job, snap: Snapshot,
-                                 pool: np.ndarray, in_groups: np.ndarray,
-                                 gload_nodes: np.ndarray,
-                                 topo_pref: np.ndarray,
-                                 weights: ScoreWeights,
-                                 colocate: float,
-                                 extra: Optional[np.ndarray] = None
-                                 ) -> Optional[List[int]]:
-        """The replaced O(n_pods × n_nodes) loop: full filter+score pass
-        and argmax once per pod, with the per-pod co-location sweep.
-        Kept verbatim as the A/B baseline the batched engine is measured
-        against in ``benchmarks/sched_scale_bench.py``."""
-        free = snap.free_gpus.copy()        # mutated as pods are placed
-        backend = self.config.score_backend
-        nodes: List[int] = []
-        for _ in range(job.n_pods):
-            mask = pool & in_groups
-            scores = compute_node_scores(
-                free, snap.used_gpus + 0, mask, gload_nodes, topo_pref,
-                job.gpus_per_pod, self.topology.gpus_per_node, weights,
-                backend=backend)
-            if extra is not None:
-                scores = np.where(scores > NEG_INF, scores + extra, scores)
-            if colocate and nodes:
-                for n in nodes:
-                    if scores[n] > NEG_INF:
-                        scores[n] += colocate
-            node = int(np.argmax(scores))
-            if scores[node] <= NEG_INF:
-                return None
-            free[node] -= job.gpus_per_pod
-            nodes.append(node)
-        return nodes
 
     # ------------------------------------------------------------------
     def _preselect_groups(self, job: Job, snap: Snapshot, pool: np.ndarray,

@@ -20,10 +20,9 @@ the weight vector select the strategy:
 
 This module is the *numpy* implementation used by the discrete-event
 simulator (cheap per call); ``repro.kernels.ref`` is the jnp oracle and
-``repro.kernels.node_score`` the Pallas TPU kernel.  All three are
-asserted identical in ``tests/test_kernels.py``.
-:func:`compute_node_scores` is the single entry point that dispatches
-between them, so RSCH can switch backends via config.
+``repro.kernels.node_score`` the Pallas TPU kernel, both behind
+``repro.kernels.ops.node_scores_and_slots``.  All three are asserted
+identical in ``tests/test_kernels.py``.
 
 **Batched gang placement** (§3.4 search-space reduction): instead of
 re-running the full score pass once per pod, a gang job is placed with
@@ -106,28 +105,6 @@ def node_scores_np(free: np.ndarray, used: np.ndarray, mask: np.ndarray,
     return np.where(valid, score, NEG_INF).astype(np.float32)
 
 
-def compute_node_scores(free: np.ndarray, used: np.ndarray,
-                        mask: np.ndarray, group_load: np.ndarray,
-                        topo_pref: np.ndarray, request: int,
-                        gpus_per_node: int, weights: ScoreWeights,
-                        backend: str = "np") -> np.ndarray:
-    """One API over the numpy reference and the jnp/Pallas kernels.
-
-    ``backend`` is ``"np"`` (default — no jax import, what the simulator
-    uses), ``"ref"`` (jnp oracle), ``"interpret"`` (Pallas interpreter,
-    CPU) or ``"pallas"`` (compiled TPU kernel).  All return the same
-    (n,) f32 score vector with ``-inf`` at invalid nodes.
-    """
-    if backend == "np":
-        return node_scores_np(free, used, mask, group_load, topo_pref,
-                              request, gpus_per_node, weights)
-    from ..kernels.ops import node_scores  # deferred: keep np path jax-free
-    return np.asarray(node_scores(
-        free, used, mask.astype(np.int32), group_load, topo_pref,
-        request=request, gpus_per_node=gpus_per_node, weights=weights,
-        backend=backend))
-
-
 def pod_slots_np(free: np.ndarray, scores: np.ndarray,
                  request: int) -> np.ndarray:
     """Capacity expansion: pod slots contributed by each scored node."""
@@ -160,14 +137,14 @@ def _prefilter_np(scores: np.ndarray, slots: np.ndarray,
 
 def chains_nondecreasing(fit_weight: float, colocate_bonus: float) -> bool:
     """True when every node's slot-value chain is nondecreasing in the
-    slot index — the precondition for the vectorized top-k engine.
+    slot index — the precondition for the vectorized top-k selection.
 
     ``slot(i, p) = base[i] + colocate_bonus·p (+ fit_weight at the last
     slot when free is an exact multiple of request)``, so consecutive
     deltas are ``colocate_bonus`` everywhere except into the final
     exact-fit slot, where the delta is ``colocate_bonus + fit_weight``.
     Builtin profiles satisfy both (bonus 2.0, fit ≥ 0); plugins may
-    contribute negative weights, in which case the heap engine is used.
+    contribute negative weights, in which case the heap walk is used.
     """
     return colocate_bonus >= 0.0 and colocate_bonus + fit_weight >= 0.0
 
@@ -176,7 +153,7 @@ def emit_slot_chains(cand: np.ndarray, scores: np.ndarray,
                      free: np.ndarray, slots: np.ndarray, request: int,
                      n_pods: int, fit_weight: float,
                      colocate_bonus: float) -> List[int]:
-    """Exact f64 epilogue shared by the numpy and kernel top-k paths.
+    """Exact f64 top-k epilogue of :func:`select_gang_slots`.
 
     With nondecreasing chains (:func:`chains_nondecreasing`) the lazy
     heap provably emits each popped node's ENTIRE chain consecutively:
@@ -203,49 +180,15 @@ def emit_slot_chains(cand: np.ndarray, scores: np.ndarray,
     return np.repeat(cand[order], counts)[:n_pods].tolist()
 
 
-def select_gang_slots(scores: np.ndarray, free: np.ndarray, request: int,
-                      n_pods: int, *, fit_weight: float = 0.0,
-                      colocate_bonus: float = 0.0,
-                      slots: Optional[np.ndarray] = None,
-                      engine: str = "heap"
-                      ) -> Optional[List[int]]:
-    """Capacity-aware top-k slot selection for a whole gang at once.
-
-    ``scores`` is the fused filter+score output for the *snapshot* free
-    counts (slot 0 of every node).  Returns the node index for each pod
-    in placement order, or ``None`` when fewer than ``n_pods`` slots
-    exist.
-
-    ``engine`` selects the implementation — all exact-identical:
-
-    * ``"heap"`` — the lazy-greedy heap pop (the A/B oracle).  One
-      entry per node, so each pop is the argmax the sequential loop
-      would have taken (ties break toward the lower node index,
-      matching ``np.argmax``).
-    * ``"topk"`` — vectorized sort + chain emission
-      (:func:`emit_slot_chains`), O(k log k) after an O(n) prefilter
-      with no Python loop.
-    * ``"topk_kernel"`` — same epilogue behind a ``jax.lax.top_k``
-      prefilter (``repro.kernels.ops.gang_slot_prefilter``).
-
-    The vectorized engines require nondecreasing slot chains; when
-    plugin weights violate that (:func:`chains_nondecreasing`), they
-    fall back to the heap automatically.
-    """
-    free = np.asarray(free)
-    if slots is None:
-        slots = pod_slots_np(free, scores, request)
-    if int(slots.sum()) < n_pods:
-        return None
-    if engine != "heap" and chains_nondecreasing(fit_weight,
-                                                 colocate_bonus):
-        if engine == "topk_kernel":
-            from ..kernels.ops import gang_slot_prefilter  # deferred
-            cand = gang_slot_prefilter(scores, slots, n_pods)
-        else:
-            cand = _prefilter_np(scores, slots, n_pods)
-        return emit_slot_chains(cand, scores, free, slots, request,
-                                n_pods, fit_weight, colocate_bonus)
+def _heap_slots(scores: np.ndarray, free: np.ndarray, slots: np.ndarray,
+                request: int, n_pods: int, fit_weight: float,
+                colocate_bonus: float) -> List[int]:
+    """The lazy-greedy heap walk over the per-node slot chains, for
+    ``slots`` that hold at least ``n_pods`` slots.  One entry per node,
+    so each pop is the argmax the sequential per-pod loop would have
+    taken (ties break toward the lower node index, matching
+    ``np.argmax``).  It serves chains of any shape; the tests hold the
+    vectorized selection to it."""
     cand = _prefilter_np(scores, slots, n_pods)
     # Per-node slot chains.  base strips the slot-0 exact-fit term so it
     # can be re-added at whichever slot the fit actually moves to.
@@ -273,3 +216,35 @@ def select_gang_slots(scores: np.ndarray, free: np.ndarray, request: int,
         if placed[c] < cslots[c]:
             heapq.heappush(heap, (-slot_value(c, placed[c]), i, c))
     return order
+
+
+def select_gang_slots(scores: np.ndarray, free: np.ndarray, request: int,
+                      n_pods: int, *, fit_weight: float = 0.0,
+                      colocate_bonus: float = 0.0,
+                      slots: Optional[np.ndarray] = None
+                      ) -> Optional[List[int]]:
+    """Capacity-aware top-k slot selection for a whole gang at once.
+
+    ``scores`` is the fused filter+score output for the *snapshot* free
+    counts (slot 0 of every node).  Returns the node index for each pod
+    in placement order, or ``None`` when fewer than ``n_pods`` slots
+    exist.
+
+    With nondecreasing slot chains (:func:`chains_nondecreasing`) the
+    selection is vectorized: an O(n) prefilter, then a sort of the
+    candidates and chain emission (:func:`emit_slot_chains`), with no
+    Python loop.  When plugin weights make the chains decreasing it
+    takes the heap walk (:func:`_heap_slots`).  Both pick what the
+    sequential per-pod argmax loop would have picked, in its order.
+    """
+    free = np.asarray(free)
+    if slots is None:
+        slots = pod_slots_np(free, scores, request)
+    if int(slots.sum()) < n_pods:
+        return None
+    if not chains_nondecreasing(fit_weight, colocate_bonus):
+        return _heap_slots(scores, free, slots, request, n_pods,
+                           fit_weight, colocate_bonus)
+    cand = _prefilter_np(scores, slots, n_pods)
+    return emit_slot_chains(cand, scores, free, slots, request, n_pods,
+                            fit_weight, colocate_bonus)

@@ -30,6 +30,7 @@ from repro.core.scoring import ScoreWeights
 from repro.core.snapshot import FullSnapshotter
 from repro.core.topology import ClusterTopology, small_topology
 from conftest import make_qsch
+from oracles import SequentialRSCH
 
 
 def _snap(state):
@@ -230,8 +231,8 @@ def test_feasible_honors_custom_filter_chain(topo, state):
 
 @pytest.mark.parametrize("batched", [True, False])
 def test_custom_score_plugin_changes_placement(topo, state, batched):
-    """An additive Score term flips the winner; batched and sequential
-    engines agree on plugin-augmented scores."""
+    """An additive Score term flips the winner, in the batched slot walk
+    and in the sequential oracle alike."""
     class PreferNode(ScorePlugin):
         name = "_PreferNode"
 
@@ -252,14 +253,16 @@ def test_custom_score_plugin_changes_placement(topo, state, batched):
         inference=make_profile("i", single_pass_plan(binpack_pass())),
         best_effort=make_profile("b", single_pass_plan(binpack_pass())),
     )
-    rsch = RSCH(topo, RSCHConfig(batched_gang=batched), profiles=profiles)
+    rsch_class = RSCH if batched else SequentialRSCH
+    rsch = rsch_class(topo, profiles=profiles)
     r = rsch.schedule(_job(1, gpus=4), _snap(state))
     assert r.placement.pods[0].node == 3
 
 
 @pytest.mark.parametrize("n_pods,gpus", [(4, 8), (8, 4), (12, 2)])
 def test_batched_matches_sequential_with_extra_scorer(topo, n_pods, gpus):
-    """Parity of the two engines must survive non-fused score terms."""
+    """Parity with the sequential oracle must survive non-fused score
+    terms."""
     rng = np.random.default_rng(42)
     state = ClusterState.create(topo)
     for node in range(state.n_nodes):
@@ -269,18 +272,17 @@ def test_batched_matches_sequential_with_extra_scorer(topo, n_pods, gpus):
     snap = _snap(state)
     job = _job(1, n_pods=n_pods, gpus=gpus)
 
-    def mk(batched):
+    def mk(rsch_class):
         profiles = ProfileSet(
             train=make_profile("t", single_pass_plan(ebinpack_pass(
                 colocate=2.0, extra_scorers=(GfrAwareScore(weight=3.0),)))),
             inference=make_profile("i", single_pass_plan(spread_pass())),
             best_effort=make_profile("b", single_pass_plan(binpack_pass())),
         )
-        return RSCH(topo, RSCHConfig(batched_gang=batched),
-                    profiles=profiles)
+        return rsch_class(topo, profiles=profiles)
 
-    rb = mk(True).schedule(job, snap)
-    rs = mk(False).schedule(job, snap)
+    rb = mk(RSCH).schedule(job, snap)
+    rs = mk(SequentialRSCH).schedule(job, snap)
     assert (rb.placement is None) == (rs.placement is None)
     if rb.placement is not None:
         assert [(p.node, p.gpu_indices) for p in rb.placement.pods] == \

@@ -11,8 +11,9 @@ pytest.importorskip("hypothesis", reason="install .[test] for the "
 from hypothesis import given, settings, strategies as st
 
 from repro.core.scoring import (BINPACK, E_BINPACK, E_SPREAD, NEG_INF,
-                                SPREAD, ScoreWeights, node_scores_np)
-from repro.kernels.ops import best_node, node_scores
+                                SPREAD, ScoreWeights, node_scores_np,
+                                select_gang_slots)
+from repro.kernels.ops import node_scores_and_slots
 
 
 def _table(rng, n, g=8):
@@ -28,6 +29,17 @@ STRATEGIES = [BINPACK, E_BINPACK, SPREAD, E_SPREAD,
               ScoreWeights(used=0.3, fit=-0.2, group=1.1, topo=-0.7)]
 
 
+def _scores(*cols, **kw):
+    """The scores of the fused (scores, slots) call, as numpy."""
+    return np.asarray(node_scores_and_slots(*cols, **kw)[0])
+
+
+def _argmax_node(scores) -> int:
+    """The argmax of the scores; -1 where no node is valid."""
+    idx = int(np.argmax(scores))
+    return -1 if scores[idx] <= NEG_INF else idx
+
+
 @given(n=st.integers(1, 3000), seed=st.integers(0, 99),
        strat=st.sampled_from(STRATEGIES), request=st.integers(1, 8))
 @settings(max_examples=40, deadline=None)
@@ -35,9 +47,9 @@ def test_ref_matches_numpy(n, seed, strat, request):
     rng = np.random.default_rng(seed)
     free, used, mask, gl, tp = _table(rng, n)
     want = node_scores_np(free, used, mask, gl, tp, request, 8, strat)
-    got = node_scores(free, used, mask, gl, tp, request=request,
-                      gpus_per_node=8, weights=strat, backend="ref")
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
+    got = _scores(free, used, mask, gl, tp, request=request,
+                  gpus_per_node=8, weights=strat, backend="ref")
+    np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
 @pytest.mark.parametrize("n", [1, 7, 128, 1000, 8192, 8193])
@@ -45,12 +57,11 @@ def test_ref_matches_numpy(n, seed, strat, request):
 def test_pallas_interpret_matches_ref(n, strat):
     rng = np.random.default_rng(n)
     free, used, mask, gl, tp = _table(rng, n)
-    ref = node_scores(free, used, mask, gl, tp, request=4,
-                      gpus_per_node=8, weights=strat, backend="ref")
-    pal = node_scores(free, used, mask, gl, tp, request=4,
-                      gpus_per_node=8, weights=strat, backend="interpret")
-    np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
-                               rtol=1e-6)
+    ref = _scores(free, used, mask, gl, tp, request=4, gpus_per_node=8,
+                  weights=strat, backend="ref")
+    pal = _scores(free, used, mask, gl, tp, request=4, gpus_per_node=8,
+                  weights=strat, backend="interpret")
+    np.testing.assert_allclose(pal, ref, rtol=1e-6)
 
 
 def test_padding_rows_never_win():
@@ -62,17 +73,16 @@ def test_padding_rows_never_win():
     mask[17] = True
     gl = np.zeros(n, np.float32)
     tp = np.zeros(n, np.float32)
-    idx = best_node(free, used, mask, gl, tp, request=4, gpus_per_node=8,
-                    weights=E_BINPACK, backend="interpret")
-    assert idx == 17
+    scores = _scores(free, used, mask, gl, tp, request=4, gpus_per_node=8,
+                     weights=E_BINPACK, backend="interpret")
+    assert _argmax_node(scores) == 17
 
 
 @pytest.mark.parametrize("backend", ["ref", "interpret"])
 @pytest.mark.parametrize("n", [1, 130, 1000, 8193])
 def test_scores_and_slots_fused_pass(backend, n):
     """Batched gang placement front half: the fused (scores, slots) pass
-    agrees with the scalar score kernel + floor(free/request) expansion."""
-    from repro.kernels.ops import node_scores_and_slots
+    agrees with numpy's scores + floor(free/request) expansion."""
     rng = np.random.default_rng(n)
     free, used, mask, gl, tp = _table(rng, n)
     scores, slots = node_scores_and_slots(
@@ -90,7 +100,6 @@ def test_scores_and_slots_phases_under_observer(counts):
     phases once each, in order, counts the bytes each way (where the
     observer counts), and returns the same numpy arrays as untraced."""
     from conftest import PhaseLog
-    from repro.kernels.ops import node_scores_and_slots
     n = 1000
     cols = _table(np.random.default_rng(5), n)
     kw = dict(request=4, gpus_per_node=8, weights=E_BINPACK,
@@ -117,25 +126,6 @@ def test_scores_and_slots_phases_under_observer(counts):
     assert log.counted == want_counts
 
 
-def _eager_chain(cols, n, kw):
-    """The score call as it was before host staging: per column a jnp
-    pad, concatenate and reshape to the kernel's tiling, the kernels,
-    and a reshape and slice of each output, all eager."""
-    from repro.kernels import node_score as ns
-    padded = -(-n // (ns.LANE * ns.BLOCK_ROWS)) * ns.LANE * ns.BLOCK_ROWS
-    tiles = []
-    for c, fill in zip(cols, (0, 0, 0, 0.0, 0.0)):
-        c = jnp.asarray(c)
-        c = jnp.concatenate([c, jnp.full((padded - n,), fill, c.dtype)])
-        tiles.append(c.reshape(-1, ns.LANE))
-    scores, slots = ns.node_scores_slots_pallas(*tiles, interpret=True,
-                                                **kw)
-    alone = ns.node_scores_pallas(*tiles, interpret=True, **kw)
-    return (np.asarray(scores.reshape(padded)[:n]),
-            np.asarray(slots.reshape(padded)[:n]),
-            np.asarray(alone.reshape(padded)[:n]))
-
-
 def _typed_table(rng, n, counts, mask, loads):
     free, used, m, gl, tp = _table(rng, n)
     return (free.astype(counts), used.astype(counts), m.astype(mask),
@@ -148,21 +138,44 @@ _KW = dict(request=4, gpus_per_node=8, w_used=E_BINPACK.used,
            w_topo=E_BINPACK.topo)
 
 
+def _assert_matches_references(cols, scores, slots):
+    """The score call's results against the jnp oracle
+    (``kernels/ref.py``) and numpy (``node_scores_np``): slots exactly,
+    scores to float32 rounding, and NEG_INF exactly at invalid nodes.
+    On the CPU, XLA contracts the references' multiply-adds differently
+    from the interpreted kernel's, so scores may differ in the last bit;
+    on the chip they agree bit for bit (``chip_smoke.py``)."""
+    from repro.kernels.ref import node_scores_slots_ref
+    free, used, mask, gl, tp = cols
+    ref_scores, ref_slots = node_scores_slots_ref(
+        *(jnp.asarray(c) for c in cols), **_KW)
+    np_scores = node_scores_np(free, used, mask != 0, gl, tp,
+                               _KW["request"], _KW["gpus_per_node"],
+                               E_BINPACK)
+    np.testing.assert_array_equal(slots, np.asarray(ref_slots))
+    np.testing.assert_array_equal(
+        slots, np.where(np_scores > NEG_INF, free // _KW["request"], 0))
+    for want in (np.asarray(ref_scores), np_scores):
+        np.testing.assert_allclose(scores, want, rtol=1e-6)
+    invalid = (mask == 0) | (free < _KW["request"])
+    np.testing.assert_array_equal(scores == NEG_INF, invalid)
+    np.testing.assert_array_equal(np_scores == NEG_INF, invalid)
+
+
 @pytest.mark.parametrize("dtypes", [("int64", "bool", "float64"),
                                     ("int32", "int32", "float32"),
                                     ("int32", "bool", "float32")],
                          ids=["wide", "rsch", "table"])
 @pytest.mark.parametrize("n", [1, 128, 130, 1000, 1024, 8191, 8192, 8193,
                                10000])
-def test_staged_call_equals_eager_chain(n, dtypes):
-    """Host staging and one program give the eager chain's scores and
-    slots bit for bit, for every column dtype the callers hand in, and
-    the jnp oracle's slots exactly and scores to float32 rounding.  A
-    table below one block is staged at its own height in whole (8, 128)
-    tiles; from one block up, in whole blocks."""
+def test_staged_call_matches_references(n, dtypes):
+    """Host staging and one program give the references' slots exactly
+    and scores to float32 rounding, NEG_INF exactly at invalid nodes,
+    for every column dtype the callers hand in.  A table below one
+    block is staged at its own height in whole (8, 128) tiles; from one
+    block up, in whole blocks."""
     from repro.kernels import node_score as ns
-    from repro.kernels.ops import node_scores_and_slots, stage_tables
-    from repro.kernels.ref import node_scores_slots_ref
+    from repro.kernels.ops import stage_tables
     cols = _typed_table(np.random.default_rng(n), n, *dtypes)
     rows = stage_tables(cols, n)[0].shape[1]
     block = ns.LANE * ns.BLOCK_ROWS
@@ -171,27 +184,17 @@ def test_staged_call_equals_eager_chain(n, dtypes):
         assert rows == -(-n // (ns.LANE * ns.SUBLANE)) * ns.SUBLANE
     else:
         assert rows * ns.LANE == -(-n // block) * block
-    want_scores, want_slots, want_alone = _eager_chain(cols, n, _KW)
     scores, slots = node_scores_and_slots(*cols, backend="interpret",
                                           **_KW)
     assert scores.dtype == np.float32 and slots.dtype == np.int32
-    np.testing.assert_array_equal(scores, want_scores)
-    np.testing.assert_array_equal(slots, want_slots)
-    alone = node_scores(*cols, backend="interpret", **_KW)
-    assert alone.shape == (n,)
-    np.testing.assert_array_equal(np.asarray(alone), want_alone)
-    # On the CPU, XLA fuses the oracle's multiply-adds differently from
-    # the interpreted kernel's, so scores may differ in the last bit.
-    ref_scores, ref_slots = node_scores_slots_ref(
-        *(jnp.asarray(c) for c in cols), **_KW)
-    np.testing.assert_array_equal(slots, np.asarray(ref_slots))
-    np.testing.assert_allclose(scores, np.asarray(ref_scores), rtol=1e-6)
+    assert scores.shape == slots.shape == (n,)
+    _assert_matches_references(cols, scores, slots)
 
 
 def test_successive_staged_calls_share_no_state():
     """A second call's staging leaves the first call's staged tables and
     results as they were, and each call scores its own columns."""
-    from repro.kernels.ops import node_scores_and_slots, stage_tables
+    from repro.kernels.ops import stage_tables
     n = 1000
     a = _typed_table(np.random.default_rng(1), n, "int64", "bool",
                      "float64")
@@ -208,9 +211,7 @@ def test_successive_staged_calls_share_no_state():
     got_a = node_scores_and_slots(*a, backend="interpret", **_KW)
     got_b = node_scores_and_slots(*b, backend="interpret", **_KW)
     for got, cols in ((got_a, a), (got_b, b)):
-        want = _eager_chain(cols, n, _KW)
-        np.testing.assert_array_equal(got[0], want[0])
-        np.testing.assert_array_equal(got[1], want[1])
+        _assert_matches_references(cols, *got)
     assert not np.array_equal(got_a[0], got_b[0])
 
 
@@ -218,11 +219,10 @@ def test_successive_staged_calls_share_no_state():
 def test_score_call_fetches_one_buffer(n):
     """The score call's program returns one int32 device array, the
     scores' float32 bits over the pod slots, and the call returns its
-    two rows as float32 scores and int32 slots, bit for bit those of the
-    five-column kernel on the same columns: NEG_INF and 0 slots at
-    masked nodes and at nodes with less free than the request."""
-    from repro.kernels.ops import (node_scores_and_slots,
-                                   scores_slots_program, stage_tables)
+    two rows as float32 scores and int32 slots, those of the references
+    on the same columns: NEG_INF and 0 slots at masked nodes and at
+    nodes with less free than the request."""
+    from repro.kernels.ops import scores_slots_program, stage_tables
     cols = _table(np.random.default_rng(n), n)
     free, _, mask, _, _ = cols
     mask[0], free[-1] = False, _KW["request"] - 1
@@ -236,9 +236,7 @@ def test_score_call_fetches_one_buffer(n):
     assert scores.shape == slots.shape == (n,)
     np.testing.assert_array_equal(np.asarray(packed).reshape(2, n),
                                   np.stack([scores.view(np.int32), slots]))
-    want_scores, want_slots, _ = _eager_chain(cols, n, _KW)
-    np.testing.assert_array_equal(scores, want_scores)
-    np.testing.assert_array_equal(slots, want_slots)
+    _assert_matches_references(cols, scores, slots)
     invalid = ~mask | (free < _KW["request"])
     assert invalid[0] and invalid[-1]
     assert (scores[invalid] == NEG_INF).all() and not slots[invalid].any()
@@ -249,9 +247,13 @@ def test_no_valid_node_returns_minus_one():
     used = np.full(64, 8, np.int32)
     mask = np.ones(64, bool)
     z = np.zeros(64, np.float32)
-    idx = best_node(free, used, mask, z, z, request=1, gpus_per_node=8,
-                    weights=BINPACK, backend="ref")
-    assert idx == -1
+    scores, slots = node_scores_and_slots(
+        free, used, mask, z, z, request=1, gpus_per_node=8,
+        weights=BINPACK, backend="ref")
+    scores, slots = np.asarray(scores), np.asarray(slots)
+    assert (scores == NEG_INF).all() and not slots.any()
+    assert _argmax_node(scores) == -1
+    assert select_gang_slots(scores, free, 1, 1, slots=slots) is None
 
 
 def test_scheduler_scoring_agrees_with_kernel(topo, state):
@@ -264,10 +266,9 @@ def test_scheduler_scoring_agrees_with_kernel(topo, state):
     gl = np.zeros(topo.n_nodes, np.float32)
     tp = np.zeros(topo.n_nodes, np.float32)
     want = node_scores_np(free, used, mask, gl, tp, 4, 8, E_BINPACK)
-    got = node_scores(free, used, mask, gl, tp, request=4,
-                      gpus_per_node=8, weights=E_BINPACK,
-                      backend="interpret")
-    np.testing.assert_allclose(np.asarray(got), want, rtol=1e-6)
+    got = _scores(free, used, mask, gl, tp, request=4, gpus_per_node=8,
+                  weights=E_BINPACK, backend="interpret")
+    np.testing.assert_allclose(got, want, rtol=1e-6)
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,7 @@ from repro.core import (ClusterState, Job, JobKind, RSCH, RSCHConfig,
                         Strategy)
 from repro.core.snapshot import FullSnapshotter
 from repro.core.topology import ClusterTopology, small_topology
+from oracles import SequentialRSCH
 
 
 def _rsch(topo, **kw):
@@ -179,8 +180,8 @@ def test_batched_matches_sequential(topo, strategy, n_pods, gpus):
     job = Job(uid=1, tenant="t0", gpu_type=0, n_pods=n_pods,
               gpus_per_pod=gpus, kind=kind, gang=(kind is JobKind.TRAIN))
     kw = dict(train_strategy=strategy, infer_strategy=strategy)
-    rb = _rsch(topo, batched_gang=True, **kw).schedule(job, snap)
-    rs = _rsch(topo, batched_gang=False, **kw).schedule(job, snap)
+    rb = _rsch(topo, **kw).schedule(job, snap)
+    rs = SequentialRSCH(topo, RSCHConfig(**kw)).schedule(job, snap)
     assert (rb.placement is None) == (rs.placement is None)
     if rb.placement is not None:
         assert ([(p.node, p.gpu_indices) for p in rb.placement.pods]
@@ -199,7 +200,7 @@ def test_batched_slot_expansion_colocates(topo, state):
 
 
 def test_batched_gang_all_or_nothing(topo, state):
-    rsch = _rsch(topo, batched_gang=True)
+    rsch = _rsch(topo)
     res = rsch.schedule(_train_job(uid=1, n_pods=17, gpus=8), _snap(state))
     assert res.placement is None
     assert state.total_allocated() == 0

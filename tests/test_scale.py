@@ -7,12 +7,15 @@ import pytest
 from repro.core import (ClusterState, Job, JobKind, QSCH, QSCHConfig,
                         QueuePolicy, QuotaManager, RSCH, RSCHConfig,
                         Strategy)
-from repro.core.scoring import (NEG_INF, chains_nondecreasing,
+from repro.core.framework import HealthFilter
+from repro.core.scoring import (NEG_INF, _heap_slots, _prefilter_np,
+                                chains_nondecreasing, pod_slots_np,
                                 select_gang_slots)
 from repro.core.simulator import SimConfig, Simulator
 from repro.core.snapshot import FullSnapshotter
 from repro.core.topology import small_topology
 from conftest import make_qsch
+from oracles import SequentialRSCH
 
 
 # ----------------------------------------------------------------------
@@ -50,8 +53,18 @@ def test_derived_columns_survive_direct_setup_writes(topo):
 
 
 # ----------------------------------------------------------------------
-# Vectorized top-k slot engine == heap oracle
+# Vectorized top-k slot selection == heap oracle
 # ----------------------------------------------------------------------
+def _heap(scores, free, request, n_pods, fit_weight=0.0,
+          colocate_bonus=0.0):
+    """The heap walk over the same slots, or None where too few exist."""
+    slots = pod_slots_np(free, scores, request)
+    if int(slots.sum()) < n_pods:
+        return None
+    return _heap_slots(scores, free, slots, request, n_pods, fit_weight,
+                       colocate_bonus)
+
+
 def _random_case(rng, engineable=True):
     n = int(rng.integers(1, 200))
     free = rng.integers(0, 9, size=n).astype(np.int64)
@@ -76,12 +89,9 @@ def test_topk_engine_matches_heap_fuzz():
     rng = np.random.default_rng(0)
     for _ in range(400):
         scores, free, request, n_pods, fit, colo = _random_case(rng)
-        heap = select_gang_slots(scores, free, request, n_pods,
-                                 fit_weight=fit, colocate_bonus=colo,
-                                 engine="heap")
+        heap = _heap(scores, free, request, n_pods, fit, colo)
         topk = select_gang_slots(scores, free, request, n_pods,
-                                 fit_weight=fit, colocate_bonus=colo,
-                                 engine="topk")
+                                 fit_weight=fit, colocate_bonus=colo)
         assert heap == topk
 
 
@@ -90,46 +100,46 @@ def test_topk_engine_edge_cases():
     free = np.asarray([8, 8], dtype=np.int64)
     scores = np.asarray([1.0, 1.0], dtype=np.float32)
     for n_pods in (1, 2, 4):
-        assert (select_gang_slots(scores, free, 4, n_pods, engine="topk")
-                == select_gang_slots(scores, free, 4, n_pods,
-                                     engine="heap"))
-    assert select_gang_slots(scores, free, 8, 3, engine="topk") is None
+        assert (select_gang_slots(scores, free, 4, n_pods)
+                == _heap(scores, free, 4, n_pods))
+    assert select_gang_slots(scores, free, 8, 3) is None
     one = select_gang_slots(np.asarray([0.5], dtype=np.float32),
                             np.asarray([8], dtype=np.int64), 2, 4,
-                            fit_weight=0.5, colocate_bonus=2.0,
-                            engine="topk")
+                            fit_weight=0.5, colocate_bonus=2.0)
     assert one == [0, 0, 0, 0]
 
 
 def test_decreasing_chains_fall_back_to_heap():
     """Negative colocate bonus violates the top-k precondition; the
-    engine kwarg must silently use the exact heap path."""
+    selection must silently use the exact heap path."""
     rng = np.random.default_rng(1)
     for _ in range(50):
         scores, free, request, n_pods, fit, colo = _random_case(
             rng, engineable=False)
         assert not chains_nondecreasing(fit, colo)
         a = select_gang_slots(scores, free, request, n_pods,
-                              fit_weight=fit, colocate_bonus=colo,
-                              engine="topk")
-        b = select_gang_slots(scores, free, request, n_pods,
-                              fit_weight=fit, colocate_bonus=colo,
-                              engine="heap")
+                              fit_weight=fit, colocate_bonus=colo)
+        b = _heap(scores, free, request, n_pods, fit, colo)
         assert a == b
 
 
-def test_topk_kernel_engine_matches_heap():
-    pytest.importorskip("jax")
-    rng = np.random.default_rng(2)
-    for _ in range(25):
-        scores, free, request, n_pods, fit, colo = _random_case(rng)
-        heap = select_gang_slots(scores, free, request, n_pods,
-                                 fit_weight=fit, colocate_bonus=colo,
-                                 engine="heap")
-        kern = select_gang_slots(scores, free, request, n_pods,
-                                 fit_weight=fit, colocate_bonus=colo,
-                                 engine="topk_kernel")
-        assert heap == kern
+def test_prefilter_keeps_lowest_indices_among_ties_at_the_cut():
+    """At the cut the prefilter keeps, among nodes whose slot-0 value
+    equals the threshold, the lowest indices: the heap pops equal
+    values lowest index first, so only those can be reached."""
+    scores = np.asarray([0.5, 1.0, 0.5, 2.0, 0.5, NEG_INF, 0.5, 1.0],
+                        dtype=np.float32)
+    slots = np.asarray([1, 1, 1, 1, 1, 0, 1, 1])
+    # Above the cut: nodes 3, 1 and 7; then two of the 0.5 ties 0, 2,
+    # 4, 6: the lowest two.
+    assert _prefilter_np(scores, slots, 5).tolist() == [0, 1, 2, 3, 7]
+    assert _prefilter_np(scores, slots, 4).tolist() == [0, 1, 3, 7]
+    # All candidates tie: the first n_pods of them.
+    flat = np.zeros(8, np.float32)
+    assert _prefilter_np(flat, np.ones(8, int), 3).tolist() == [0, 1, 2]
+    # A node without slots is no candidate, even at the threshold.
+    scores[5] = 0.5
+    assert _prefilter_np(scores, slots, 6).tolist() == [0, 1, 2, 3, 4, 7]
 
 
 # ----------------------------------------------------------------------
@@ -163,8 +173,25 @@ def test_tracked_group_sum_patch_equals_bincount(topo):
 # ----------------------------------------------------------------------
 # Subset level-2 scoring == full-width scoring
 # ----------------------------------------------------------------------
+class _OwnHealthFilter(HealthFilter):
+    """The default HealthFilter under another type: RSCH takes the
+    generic Filter path and scores the full width."""
+
+
+def _full_width_profiles(strategy):
+    from repro.core.rsch import profiles_from_config
+    profiles = profiles_from_config(RSCHConfig(train_strategy=strategy))
+    for prof in (profiles.train, profiles.inference, profiles.best_effort):
+        prof.filters = tuple(_OwnHealthFilter() if type(f) is HealthFilter
+                             else f for f in prof.filters)
+    return profiles
+
+
 @pytest.mark.parametrize("strategy", list(Strategy))
 def test_subset_scoring_matches_full_width(strategy):
+    """Subset scoring picks what the full-width batched pass (a Filter
+    subclass takes RSCH off the subset path) and the sequential per-pod
+    loop over the full width pick."""
     topo = small_topology(n_nodes=64, gpus_per_node=8, nodes_per_leaf=8)
     rng = np.random.default_rng(4)
     for trial in range(10):
@@ -179,16 +206,16 @@ def test_subset_scoring_matches_full_width(strategy):
                   gpus_per_pod=int(rng.choice([1, 2, 4, 8])),
                   kind=JobKind.TRAIN)
         fast = RSCH(topo, RSCHConfig(train_strategy=strategy))
-        slow = RSCH(topo, RSCHConfig(train_strategy=strategy,
-                                     subset_scoring=False,
-                                     slot_engine="heap"))
+        full = RSCH(topo, profiles=_full_width_profiles(strategy))
+        slow = SequentialRSCH(topo, RSCHConfig(train_strategy=strategy))
         a = fast.schedule(job, snap)
-        b = slow.schedule(job, snap)
-        if a.placement is None:
-            assert b.placement is None
-        else:
-            assert [(p.node, p.gpu_indices) for p in a.placement.pods] \
-                == [(p.node, p.gpu_indices) for p in b.placement.pods]
+        for b in (full.schedule(job, snap), slow.schedule(job, snap)):
+            if a.placement is None:
+                assert b.placement is None
+            else:
+                assert [(p.node, p.gpu_indices)
+                        for p in a.placement.pods] \
+                    == [(p.node, p.gpu_indices) for p in b.placement.pods]
 
 
 # ----------------------------------------------------------------------
